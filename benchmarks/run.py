@@ -42,20 +42,22 @@ ORDER = ["milp_vs_ccmlb", "delta_sweep", "assembly_scaling", "costmodel_eval",
 
 
 def discover():
-    """(display_name, module) for every benchmarks submodule with run()."""
+    """``(modules, failed)``: (display_name, module) for every benchmarks
+    submodule with run(), and the names of those that failed to import."""
     names = [m.name for m in pkgutil.iter_modules(benchmarks.__path__)
              if m.name not in ("run", "render_experiments")]
     names.sort(key=lambda n: (ORDER.index(n) if n in ORDER else len(ORDER), n))
-    out = []
+    out, failed = [], []
     for name in names:
         try:
             mod = importlib.import_module(f"benchmarks.{name}")
         except Exception:
             traceback.print_exc()
+            failed.append(DISPLAY.get(name, name))
             continue
         if callable(getattr(mod, "run", None)):
             out.append((DISPLAY.get(name, name), mod))
-    return out
+    return out, failed
 
 
 def _fmt(v) -> str:
@@ -128,11 +130,13 @@ def summarize_bench_json(out=print, records: bool = False):
     out("=" * 72)
 
 
-def main() -> None:
+def main() -> int:
+    """Runs the benchmarks; returns 1 when any of them failed to import or
+    to run, 0 otherwise."""
     args = [a for a in sys.argv[1:]]
     if "--summary" in args:
         summarize_bench_json(records="--records" in args)
-        return
+        return 0
     args = [a for a in args if not a.startswith("--")]
     filt = args[0] if args else ""
     print("name,us_per_call,derived")
@@ -140,7 +144,11 @@ def main() -> None:
     def report(name: str, us: float, derived: str = ""):
         print(f"{name},{us:.1f},{derived}", flush=True)
 
-    for name, mod in discover():
+    mods, failed = discover()
+    failed = [name for name in failed if not filt or filt in name]
+    for name in failed:
+        report(f"{name}_FAILED", 0.0, "import failed, see stderr")
+    for name, mod in mods:
         if filt and filt not in name:
             continue
         try:
@@ -148,8 +156,10 @@ def main() -> None:
         except Exception:
             traceback.print_exc()
             report(f"{name}_FAILED", 0.0, "see stderr")
+            failed.append(name)
     summarize_bench_json()
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -189,8 +189,8 @@ class PhaseEngine:
     ``interpret=True`` runs it through the Pallas interpreter on CPU, where
     it is bitwise-equal to numpy — the CI-exercised path) and
     ``"pallas_compiled"`` (f32 tiles on the 128-lane boundary,
-    ``interpret=False`` where a compile target exists, f32-interpret
-    fallback otherwise; assignment-identity parity tier, not bitwise).
+    ``interpret=False`` on every backend but the CPU, which interprets
+    it; assignment-identity parity tier, not bitwise).
     """
 
     def __init__(self, state: CCMState, backend: str = "numpy",

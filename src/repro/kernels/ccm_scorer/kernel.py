@@ -28,15 +28,30 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ccm_scorer.layout import AV, N_OUT, OUT, PM, SC
+
+
+class _Scalars:
+    """``sc[i]`` reads scalar i of this grid step's event.  The whole
+    (E, N_SC) scalar array sits in SMEM: a (1, N_SC) block would break the
+    TPU's (8, 128) block tiling, and the kernel only ever needs the
+    scalars one at a time."""
+
+    def __init__(self, ref):
+        self._ref = ref
+        self._e = pl.program_id(0)
+
+    def __getitem__(self, i):
+        return self._ref[self._e, i]
 
 
 def _scorer_kernel(av_ref, bv_ref, pm_ref, sc_ref, o_ref):
     av = av_ref[0]          # (N_AV, A)
     bv = bv_ref[0]          # (N_AV, B)
     pm = pm_ref[0]          # (N_PM, A, B)
-    sc = sc_ref[0]          # (N_SC,)
+    sc = _Scalars(sc_ref)   # (N_SC,) in SMEM, read one scalar at a time
     a_n = av.shape[1]
     b_n = bv.shape[1]
 
@@ -107,8 +122,9 @@ def _scorer_kernel(av_ref, bv_ref, pm_ref, sc_ref, o_ref):
              + jnp.maximum(sc[SC.ovh_b], col(AV.ovh)))
 
     # --- masked tail -----------------------------------------------------
-    ia = jax.lax.broadcasted_iota(av.dtype, (a_n, b_n), 0)
-    ib = jax.lax.broadcasted_iota(av.dtype, (a_n, b_n), 1)
+    # Mosaic builds iotas in int32 only
+    ia = jax.lax.broadcasted_iota(jnp.int32, (a_n, b_n), 0).astype(av.dtype)
+    ib = jax.lax.broadcasted_iota(jnp.int32, (a_n, b_n), 1).astype(av.dtype)
     mask = (ia <= sc[SC.na]) & (ib <= sc[SC.nb])
     zero = jnp.zeros((), av.dtype)
     inf = jnp.full((), jnp.inf, av.dtype)
@@ -132,7 +148,6 @@ def score_tiles_fwd(av, bv, pm, sc, *, interpret: bool = True):
     e_n, n_av, a_n = av.shape
     b_n = bv.shape[2]
     n_pm = pm.shape[1]
-    n_sc = sc.shape[1]
     return pl.pallas_call(
         _scorer_kernel,
         grid=(e_n,),
@@ -140,7 +155,7 @@ def score_tiles_fwd(av, bv, pm, sc, *, interpret: bool = True):
             pl.BlockSpec((1, n_av, a_n), lambda e: (e, 0, 0)),
             pl.BlockSpec((1, n_av, b_n), lambda e: (e, 0, 0)),
             pl.BlockSpec((1, n_pm, a_n, b_n), lambda e: (e, 0, 0, 0)),
-            pl.BlockSpec((1, n_sc), lambda e: (e, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, N_OUT, a_n, b_n), lambda e: (e, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((e_n, N_OUT, a_n, b_n), av.dtype),

@@ -74,9 +74,10 @@ def ccm_score_tiles(av: np.ndarray, bv: np.ndarray, pm: np.ndarray,
 
     ``numpy`` (the reference), ``jit`` (bucketed compiled f64) and
     ``pallas`` (interpret mode) return (E, N_OUT, A, B) float64 and agree
-    BITWISE.  ``pallas_compiled`` scores in f32 on 128-lane tiles
-    (interpret fallback off-TPU) and returns the exact f32 values upcast
-    to float64 — ulp-level approximate, assignment-identity parity tier.
+    BITWISE on the CPU backend, and refuse any other.  ``pallas_compiled``
+    scores in f32 on 128-lane tiles (interpreted on the CPU backend only)
+    and returns the exact f32 values upcast to float64 — ulp-level
+    approximate, assignment-identity parity tier.
     """
     if backend == "numpy":
         return ref.score_tiles(av, bv, pm, sc)
@@ -84,12 +85,8 @@ def ccm_score_tiles(av: np.ndarray, bv: np.ndarray, pm: np.ndarray,
         from repro.kernels.ccm_scorer import jit as scorer_jit
         return scorer_jit.score_tiles_jit(av, bv, pm, sc)
     if backend == "pallas":
-        import jax  # deferred: the numpy path must not require jax
-
-        from repro.kernels.ccm_scorer.kernel import score_tiles_fwd
-        with jax.experimental.enable_x64():
-            out = score_tiles_fwd(av, bv, pm, sc, interpret=interpret)
-        return np.asarray(out)
+        from repro.kernels.ccm_scorer import jit as scorer_jit
+        return scorer_jit._pallas_score(av, bv, pm, sc, interpret=interpret)
     if backend == "pallas_compiled":
         from repro.kernels.ccm_scorer import jit as scorer_jit
         return scorer_jit.score_tiles_f32(av, bv, pm, sc)

@@ -7,7 +7,7 @@ override lives only in launch/dryrun.py.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.sharding import MeshAxes
 
@@ -15,7 +15,7 @@ from repro.sharding import MeshAxes
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -23,6 +23,7 @@ def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
     return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
                          devices=jax.devices()[: data * model])
 
 

@@ -11,43 +11,48 @@ applied as function-preserving slot permutations.
 from __future__ import annotations
 
 import argparse
+import functools
 import time
-from pathlib import Path
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.balance.expert_placement import (apply_expert_permutation,
-                                            plan_expert_placement)
+from repro.balance.expert_placement import plan_expert_placement
 from repro.checkpoint import CheckpointManager
 from repro.data.pipeline import make_batch
 from repro.launch.mesh import make_local_mesh, make_production_mesh
-from repro.launch.steps import (abstract_opt, abstract_params, make_train_step,
-                                named)
+from repro.launch.steps import abstract_opt, abstract_params, make_train_step
 from repro.models.layers import split_lp_tree
-from repro.models.model import batch_specs, build_model
+from repro.models.model import build_model
 from repro.optim import adamw_init
-from repro.runtime.fault import FaultInjector, NodeFailure, run_with_restarts
+from repro.runtime.fault import FaultInjector, run_with_restarts
 from repro.runtime.straggler import StragglerTracker
 
 
 def train_loop(cfg, mesh, *, steps: int, seq_len: int, global_batch: int,
                ckpt_dir: Optional[str] = None, ckpt_every: int = 50,
                rebalance_every: int = 0, fault: Optional[FaultInjector] = None,
-               lr: float = 3e-4, log_every: int = 10, seed: int = 0):
+               lr: float = 3e-4, log_every: int = 10, seed: int = 0,
+               hbm_budget_bytes: Optional[float] = None):
+    """``hbm_budget_bytes`` is the per-device budget of the expert replan;
+    ``None`` reads it from the device (see ``rebalance_experts``)."""
     model = build_model(cfg, mesh)
     params_sds, p_sh = abstract_params(model)
+    opt_sds, o_sh = abstract_opt(params_sds, p_sh)
+    # the step returns params and moments placed as they came in, so every
+    # step after the first reuses its program
     step_fn = jax.jit(make_train_step(model, lr=lr,
                                       warmup_steps=max(1, steps // 10),
                                       total_steps=steps),
+                      out_shardings=(p_sh, o_sh, None),
                       donate_argnums=(0, 1))
 
     mgr = CheckpointManager(ckpt_dir) if ckpt_dir else None
     start = 0
     if mgr and mgr.latest() is not None:
-        opt_sds, o_sh = abstract_opt(params_sds, p_sh)
         (params, opt_state), start = mgr.restore((params_sds, opt_sds),
                                                  (p_sh, o_sh))
         print(f"[train] restored step {start}")
@@ -55,7 +60,7 @@ def train_loop(cfg, mesh, *, steps: int, seq_len: int, global_batch: int,
         lp = model.init(jax.random.key(seed))
         params, _ = split_lp_tree(lp)
         params = jax.device_put(params, p_sh)
-        opt_state = adamw_init(params)
+        opt_state = jax.device_put(adamw_init(params), o_sh)
 
     tracker = StragglerTracker(n_ranks=mesh.devices.size)
     losses = []
@@ -77,54 +82,77 @@ def train_loop(cfg, mesh, *, steps: int, seq_len: int, global_batch: int,
         if (rebalance_every and cfg.is_moe and (step + 1) % rebalance_every == 0
                 and "expert_counts" in metrics):
             counts = np.asarray(metrics["expert_counts"])  # (periods, E)
-            params = rebalance_experts(params, counts, cfg, mesh, tracker)
+            params, opt_state, _ = rebalance_experts(
+                params, opt_state, counts, cfg, mesh,
+                hbm_budget_bytes=hbm_budget_bytes)
     if mgr:
         mgr.wait()
     return params, opt_state, losses
 
 
-def rebalance_experts(params, counts, cfg, mesh, tracker):
-    """CCM-LB plan -> per-layer slot permutation applied to live params."""
+def _permute_experts(params, opt_state, perms, cfg):
+    """Apply the per-period slot permutations ``perms`` (periods, E) to the
+    expert weights and router of every MoE block, and to their AdamW
+    moments, so each moment stays with the weight it belongs to."""
+    def one_tree(tree):
+        scan = dict(tree["scan"])
+        for i, kind in enumerate(cfg.block_pattern):
+            if kind != "moe":
+                continue
+            blk = dict(scan[f"b{i}"])
+            moe = dict(blk["moe"])
+            for name, axis in (("w_gate", 0), ("w_up", 0), ("w_down", 0),
+                               ("router", 1)):
+                moe[name] = jax.vmap(
+                    lambda sl, p, axis=axis: jnp.take(sl, p, axis=axis))(
+                        moe[name], perms)
+            blk["moe"] = moe
+            scan[f"b{i}"] = blk
+        out = dict(tree)
+        out["scan"] = scan
+        return out
+
+    return one_tree(params), opt_state._replace(m=one_tree(opt_state.m),
+                                                v=one_tree(opt_state.v))
+
+
+def rebalance_experts(params, opt_state, counts, cfg, mesh, *,
+                      hbm_budget_bytes: Optional[float] = None):
+    """CCM-LB plan -> per-layer slot permutation applied to live params and
+    to the optimizer state.  Returns ``(params, opt_state, plan)``; ``plan``
+    is ``None`` when nothing was applied.
+
+    The plan's per-device budget is ``hbm_budget_bytes`` or, when that is
+    ``None``, the limit the device reports; a backend that reports none
+    needs it passed."""
     n_model = int(mesh.shape["model"])
     n_dev = max(n_model, 1)
-    if cfg.num_experts % n_dev:
-        return params
+    if n_dev == 1 or cfg.num_experts % n_dev:
+        return params, opt_state, None
+    if hbm_budget_bytes is None:
+        stats = mesh.devices.flat[0].memory_stats() or {}
+        if "bytes_limit" not in stats:
+            raise ValueError("the backend reports no device memory limit: "
+                             "pass hbm_budget_bytes")
+        hbm_budget_bytes = float(stats["bytes_limit"])
     plan = plan_expert_placement(
         counts, cfg, n_dev,
-        hbm_budget_bytes=16e9,
+        hbm_budget_bytes=hbm_budget_bytes,
         rank_speed=None)
     if plan.max_work_after >= plan.max_work_before:
-        return params
-    scan = dict(params["scan"])
-    for i, kind in enumerate(cfg.block_pattern):
-        if kind != "moe":
-            continue
-        blk = dict(scan[f"b{i}"])
-        moe = dict(blk["moe"])
-        # apply the (layer-period-averaged) permutation of layer 0 to all
-        # periods symmetrically: per-period perms would need per-period
-        # stats; counts are per period already.
-        import jax.numpy as jnp
-        perms = jnp.asarray(plan.permutations)  # (periods, E)
-
-        def permute(leaf, axis):
-            def one(sl, p):
-                return jnp.take(sl, p, axis=axis)
-            return jax.vmap(one)(leaf, perms)
-
-        moe["w_gate"] = permute(moe["w_gate"], 0)
-        moe["w_up"] = permute(moe["w_up"], 0)
-        moe["w_down"] = permute(moe["w_down"], 0)
-        moe["router"] = permute(moe["router"], 1)
-        blk["moe"] = moe
-        scan[f"b{i}"] = blk
-    out = dict(params)
-    out["scan"] = scan
+        return params, opt_state, None
+    # apply every period's permutation in one program that keeps each
+    # leaf's sharding, so the experts stay spread over the model axis
+    shardings = jax.tree.map(lambda a: a.sharding, (params, opt_state))
+    params, opt_state = jax.jit(
+        functools.partial(_permute_experts, cfg=cfg),
+        out_shardings=shardings, donate_argnums=(0, 1))(
+            params, opt_state, jnp.asarray(plan.permutations))
     print(f"[ccm-lb] expert re-placement: imbalance "
           f"{plan.imbalance_before:.3f} -> {plan.imbalance_after:.3f} "
           f"(replication suggested on {plan.replicated_blocks} blocks)",
           flush=True)
-    return out
+    return params, opt_state, plan
 
 
 def main():

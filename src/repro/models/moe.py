@@ -16,27 +16,13 @@ the communication term.
 from __future__ import annotations
 
 import functools
-import inspect
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6: top-level API
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-# the replication-check kwarg was renamed check_rep -> check_vma on a
-# different release than the jax.shard_map promotion, so key on the
-# signature rather than the API location
-_sig = inspect.signature(_shard_map).parameters
-_SHARD_MAP_KW = ({"check_vma": False} if "check_vma" in _sig
-                 else {"check_rep": False} if "check_rep" in _sig else {})
-del _sig
-
 from repro.configs.base import ModelConfig
-from repro.models.layers import LP, activation, dense_init
+from repro.models.layers import activation, dense_init
 from repro.sharding import MeshAxes
 
 
@@ -130,7 +116,7 @@ def moe_forward(params, x, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes,
         _local_moe, cfg=cfg, axes=axes, act_name=act_name,
         model_size=int(mesh.shape[axes.model]),
         data_size=int(mesh.shape[axes.data]))
-    y, aux, counts = _shard_map(
+    y, aux, counts = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -141,7 +127,7 @@ def moe_forward(params, x, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes,
             P(bspec, None, None),                # x
         ),
         out_specs=(P(bspec, None, None), P(), P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(params["router"], params["w_gate"], params["w_up"], params["w_down"], x)
 
     if cfg.num_shared_experts:

@@ -239,8 +239,37 @@ def test_pallas_compiled_ulp_budget_adversarial():
 
 
 def test_pallas_compiled_fallback_reporting():
-    """Off-TPU the compiled path must degrade to f32 interpret and say so."""
+    """On the CPU backend the compiled path interprets the f32 kernel and
+    says so."""
+    import jax
+    assert jax.default_backend() == "cpu"
     av, bv, pm, sc = _random_tiles(np.random.default_rng(0), 1, 4, 4)
     ops.ccm_score_tiles(av, bv, pm, sc, backend="pallas_compiled")
-    if not jit.pallas_compiled_supported():
-        assert jit.pallas_compiled_fallback()
+    assert jit.pallas_compiled_fallback()
+
+
+@pytest.mark.parametrize("backend", ["jit", "pallas"])
+def test_f64_backends_refuse_off_cpu(monkeypatch, backend):
+    """The f64 tier's bar is bit-for-bit numpy, which only XLA:CPU meets;
+    elsewhere (the TPU emulates f64) the backends refuse to run."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    av, bv, pm, sc = _random_tiles(np.random.default_rng(2), 1, 4, 4)
+    with pytest.raises(NotImplementedError, match="bitwise"):
+        ops.ccm_score_tiles(av, bv, pm, sc, backend=backend)
+    phase = random_phase(0, num_ranks=4, num_tasks=24, num_blocks=6,
+                         num_comms=24, mem_cap=1e12)
+    with pytest.raises(NotImplementedError, match="bitwise"):
+        ccm_lb(phase, initial_assignment(phase), CCMParams(), n_iter=1,
+               backend=backend)
+
+
+def test_pallas_compiled_raises_off_cpu(monkeypatch):
+    """On any backend but the CPU a kernel that cannot be compiled raises:
+    it is never interpreted in silence.  The platform is steered here; the
+    CPU then refuses ``interpret=False``, as a broken lowering would."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    av, bv, pm, sc = _random_tiles(np.random.default_rng(1), 1, 4, 4)
+    with pytest.raises(Exception, match="(?i)interpret|cpu|lower"):
+        ops.ccm_score_tiles(av, bv, pm, sc, backend="pallas_compiled")

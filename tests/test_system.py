@@ -56,6 +56,13 @@ def test_moe_training_reduces_loss_and_reports_stats():
     assert counts.sum() == pytest.approx(2 * 4 * 64 * cfg.top_k, rel=1e-6)
 
 
+def test_local_mesh_axes_are_auto():
+    """Auto axes: with_sharding_constraint refuses Explicit ones."""
+    from jax.sharding import AxisType
+    assert MESH.axis_types == (AxisType.Auto, AxisType.Auto)
+    assert MESH.axis_names == ("data", "model")
+
+
 def test_data_pipeline_deterministic():
     d1 = SyntheticLMData(1024, 64, 4, seed=3)
     d2 = SyntheticLMData(1024, 64, 4, seed=3)

@@ -1,0 +1,97 @@
+"""Compiles of the main path for a described TPU v5e, with no chip attached.
+
+The TPU compiler refuses what the Pallas interpreter and XLA:CPU accept:
+blocks off the (8, 128) tiling, non-integer iotas, programs that do not
+fit the chip.  These tests make it look at the scorer kernel and at one
+full-width qwen3-moe expert layer.  The topology is described inside a
+fixture, never at import, so that every test worker collects the same
+tests and only the worker that runs this file loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
+
+from repro import configs
+from repro.kernels.ccm_scorer.kernel import score_tiles_fwd
+from repro.kernels.ccm_scorer.layout import N_AV, N_OUT, N_PM, N_SC
+from repro.models import moe as moe_lib
+from repro.models.layers import split_lp_tree
+from repro.sharding import MeshAxes
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("events", [1, 8])
+def test_scorer_kernel_compiles_for_v5e(one_chip, no_compile_cache, events):
+    """The f32 scorer at the launcher's buckets: A on the 8-sublane grid,
+    B on the 128-lane boundary, one grid step per lock event."""
+    a_n, b_n = 8, 128
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = score_tiles_fwd.lower(
+        sds((events, N_AV, a_n)), sds((events, N_AV, b_n)),
+        sds((events, N_PM, a_n, b_n)), sds((events, N_SC)),
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    out = compiled.out_info
+    assert out.shape == (events, N_OUT, a_n, b_n)
+
+
+def test_qwen3_expert_layer_compiles_for_v5e(topo, no_compile_cache):
+    """One qwen3-moe-30b-a3b expert layer forward at its published widths
+    (d_model 2048, 128 experts, top-8, moe_d_ff 768) on one chip."""
+    cfg = configs.get_config("qwen3-moe-30b-a3b")
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    axes = MeshAxes.for_mesh(mesh)
+    params = split_lp_tree(jax.eval_shape(
+        lambda k: moe_lib.init_moe(k, cfg), jax.random.key(0)))[0]
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), params)
+    x = jax.ShapeDtypeStruct((1, 512, cfg.d_model), jnp.bfloat16, sharding=one)
+
+    def fwd(p, x):
+        return moe_lib.moe_forward(p, x, cfg, mesh, axes, cfg.act)
+
+    compiled = jax.jit(fwd).lower(params, x).compile()
+    y, stats = compiled.out_info
+    assert y.shape == x.shape
+    assert stats["expert_counts"].shape == (cfg.num_experts,)
+    mem = compiled.memory_analysis()
+    weights = 3 * cfg.num_experts * cfg.d_model * cfg.moe_d_ff * 2
+    assert mem.argument_size_in_bytes >= weights
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
