@@ -1,0 +1,15 @@
+"""Model FLOP utilization of the training window: the FLOPs the forward
+and backward passes of the cut model require per token
+(``bench/flops.py``: top-k experts, causal attention, the head over the
+vocabulary held here; no recompute, no capacity padding) times the
+window's tokens per second, over the chips' bf16 peak."""
+from bench import flops
+
+
+def read(run, trace, peaks):
+    rate = run.e2e.get("train_tokens_per_s")
+    if not rate:
+        return None
+    per_token = flops.lm_train_flops_per_token(run.config,
+                                               run.traffic["seq_len"])
+    return 100.0 * rate * per_token / (len(run.devices) * peaks["flops_bf16"])
