@@ -5,6 +5,8 @@ trainer, the server, and the multi-pod dry-run.
 from __future__ import annotations
 
 import functools
+import re
+from pathlib import Path
 from typing import Any, Tuple
 
 import jax
@@ -17,6 +19,18 @@ from repro.models.model import (Model, batch_specs, build_model, cache_specs,
                                 decode_token_specs)
 from repro.optim import adamw_init, adamw_update, warmup_cosine
 from repro.sharding import MeshAxes, shardings_for_lp_tree
+
+# The model names its layers (jax.named_scope) for profiles.  By default the
+# persistent compile cache leaves the op metadata out of its key, so a step
+# of the same computation compiled from other source (such as a version
+# without the scopes) is loaded with that source's op names.  Keying on the
+# metadata keeps a profile's names true; file names are taken relative to
+# the checkout, so that where a checkout lies does not change the key.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+if jax.config.jax_hlo_source_file_canonicalization_regex is None:
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(str(Path(__file__).resolve().parents[3]))
+                      + "/")
 
 
 def named(mesh: Mesh, spec_tree):

@@ -1,9 +1,10 @@
 """Training launcher.
 
 Runs any --arch (smoke configs on CPU; full configs are for the production
-meshes) with: checkpoint/restart fault tolerance, straggler EWMA feeding CCM
-speed factors, and — for MoE archs — periodic CCM-LB expert re-placement
-applied as function-preserving slot permutations.
+meshes) with: checkpoint/restart fault tolerance and — for MoE archs —
+periodic CCM-LB expert re-placement applied as function-preserving slot
+permutations.  Under ``jax.profiler.trace`` each step shows as a ``train``
+step span, and a replan as ``ccm_lb.plan`` and ``rebalance.permute`` spans.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-moe-30b-a3b \
       --smoke --steps 50 --rebalance-every 20
@@ -29,7 +30,6 @@ from repro.models.layers import split_lp_tree
 from repro.models.model import build_model
 from repro.optim import adamw_init
 from repro.runtime.fault import FaultInjector, run_with_restarts
-from repro.runtime.straggler import StragglerTracker
 
 
 def train_loop(cfg, mesh, *, steps: int, seq_len: int, global_batch: int,
@@ -62,17 +62,16 @@ def train_loop(cfg, mesh, *, steps: int, seq_len: int, global_batch: int,
         params = jax.device_put(params, p_sh)
         opt_state = jax.device_put(adamw_init(params), o_sh)
 
-    tracker = StragglerTracker(n_ranks=mesh.devices.size)
     losses = []
     for step in range(start, steps):
         if fault is not None:
             fault.maybe_fail(step)
         batch = make_batch(cfg, seq_len, global_batch, step, seed=seed)
         t0 = time.time()
-        params, opt_state, metrics = step_fn(params, opt_state, batch)
-        loss = float(metrics["loss"])
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            params, opt_state, metrics = step_fn(params, opt_state, batch)
+            loss = float(metrics["loss"])
         dt = time.time() - t0
-        tracker.update(np.full(mesh.devices.size, dt))
         losses.append(loss)
         if step % log_every == 0 or step == steps - 1:
             print(f"[train] step {step} loss {loss:.4f} ({dt:.2f}s)",
@@ -135,19 +134,21 @@ def rebalance_experts(params, opt_state, counts, cfg, mesh, *,
             raise ValueError("the backend reports no device memory limit: "
                              "pass hbm_budget_bytes")
         hbm_budget_bytes = float(stats["bytes_limit"])
-    plan = plan_expert_placement(
-        counts, cfg, n_dev,
-        hbm_budget_bytes=hbm_budget_bytes,
-        rank_speed=None)
+    with jax.profiler.TraceAnnotation("ccm_lb.plan"):
+        plan = plan_expert_placement(
+            counts, cfg, n_dev,
+            hbm_budget_bytes=hbm_budget_bytes,
+            rank_speed=None)
     if plan.max_work_after >= plan.max_work_before:
         return params, opt_state, None
     # apply every period's permutation in one program that keeps each
     # leaf's sharding, so the experts stay spread over the model axis
     shardings = jax.tree.map(lambda a: a.sharding, (params, opt_state))
-    params, opt_state = jax.jit(
-        functools.partial(_permute_experts, cfg=cfg),
-        out_shardings=shardings, donate_argnums=(0, 1))(
-            params, opt_state, jnp.asarray(plan.permutations))
+    with jax.profiler.TraceAnnotation("rebalance.permute"):
+        params, opt_state = jax.jit(
+            functools.partial(_permute_experts, cfg=cfg),
+            out_shardings=shardings, donate_argnums=(0, 1))(
+                params, opt_state, jnp.asarray(plan.permutations))
     print(f"[ccm-lb] expert re-placement: imbalance "
           f"{plan.imbalance_before:.3f} -> {plan.imbalance_after:.3f} "
           f"(replication suggested on {plan.replicated_blocks} blocks)",

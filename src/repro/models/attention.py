@@ -101,23 +101,25 @@ def attention_forward_kv(params, x, cfg: ModelConfig, *, mask_kind: str,
 
     Returns (out, k, v) so prefill can populate the KV cache for free.
     """
-    kv_in = x if kv_x is None else kv_x
-    q = jnp.einsum("bsd,dhe->bshe", x, params["w_q"])
-    k = jnp.einsum("bsd,dhe->bshe", kv_in, params["w_k"])
-    v = jnp.einsum("bsd,dhe->bshe", kv_in, params["w_v"])
-    if kv_x is None:  # self-attention -> RoPE
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-        kv_pos = positions
-    else:
-        kv_pos = kv_positions
-    bias = _mask_bias(positions, kv_pos, mask_kind, cfg.window_size)[:, None]
-    if cfg.attn_kv_chunk and k.shape[1] > cfg.attn_kv_chunk:
-        out = _sdpa_chunked(q, k, v, bias, cfg.logit_softcap,
-                            cfg.attn_kv_chunk)
-    else:
-        out = _sdpa(q, k, v, bias, cfg.logit_softcap)
-    return jnp.einsum("bshe,hed->bsd", out, params["w_o"]), k, v
+    with jax.named_scope("attn"):
+        kv_in = x if kv_x is None else kv_x
+        q = jnp.einsum("bsd,dhe->bshe", x, params["w_q"])
+        k = jnp.einsum("bsd,dhe->bshe", kv_in, params["w_k"])
+        v = jnp.einsum("bsd,dhe->bshe", kv_in, params["w_v"])
+        if kv_x is None:  # self-attention -> RoPE
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+            kv_pos = positions
+        else:
+            kv_pos = kv_positions
+        bias = _mask_bias(positions, kv_pos, mask_kind,
+                          cfg.window_size)[:, None]
+        if cfg.attn_kv_chunk and k.shape[1] > cfg.attn_kv_chunk:
+            out = _sdpa_chunked(q, k, v, bias, cfg.logit_softcap,
+                                cfg.attn_kv_chunk)
+        else:
+            out = _sdpa(q, k, v, bias, cfg.logit_softcap)
+        return jnp.einsum("bshe,hed->bsd", out, params["w_o"]), k, v
 
 
 def attention_forward(params, x, cfg: ModelConfig, *, mask_kind: str,
@@ -162,33 +164,35 @@ def attention_decode(params, x, cache_k, cache_v, pos, cfg: ModelConfig, *,
 
     Returns (out, new_cache_k, new_cache_v).
     """
-    b = x.shape[0]
-    s_max = cache_k.shape[1]
-    q = jnp.einsum("bsd,dhe->bshe", x, params["w_q"])
-    if not cross:
-        k_new = jnp.einsum("bsd,dhe->bshe", x, params["w_k"])
-        v_new = jnp.einsum("bsd,dhe->bshe", x, params["w_v"])
-        q = apply_rope(q, jnp.full((b, 1), pos), cfg.rope_theta)
-        k_new = apply_rope(k_new, jnp.full((b, 1), pos), cfg.rope_theta)
-        write_at = jnp.mod(pos, s_max) if ring else pos
-        cache_k = jax.lax.dynamic_update_slice_in_dim(
-            cache_k, k_new.astype(cache_k.dtype), write_at, axis=1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(
-            cache_v, v_new.astype(cache_v.dtype), write_at, axis=1)
-    if cross:
-        bias = jnp.zeros((b, 1, 1, s_max), jnp.float32)
-    elif ring:
-        slots = jnp.arange(s_max)[None, :]
-        k_pos = pos - jnp.mod(pos - slots, s_max)   # true position per slot
-        ok = k_pos >= 0
-        bias = jnp.where(ok, 0.0, -1e30).astype(jnp.float32)[:, None, None, :]
-        bias = jnp.broadcast_to(bias, (b, 1, 1, s_max))
-    else:
-        q_pos = jnp.full((b, 1), pos)
-        k_pos = jnp.arange(s_max)[None, :]
-        bias = _mask_bias(q_pos, k_pos,
-                          "local" if mask_kind == "local" else "causal",
-                          cfg.window_size)[:, None]
-    out = _sdpa(q, cache_k, cache_v, bias, cfg.logit_softcap)
-    out = jnp.einsum("bshe,hed->bsd", out, params["w_o"])
-    return out, cache_k, cache_v
+    with jax.named_scope("attn"):
+        b = x.shape[0]
+        s_max = cache_k.shape[1]
+        q = jnp.einsum("bsd,dhe->bshe", x, params["w_q"])
+        if not cross:
+            k_new = jnp.einsum("bsd,dhe->bshe", x, params["w_k"])
+            v_new = jnp.einsum("bsd,dhe->bshe", x, params["w_v"])
+            q = apply_rope(q, jnp.full((b, 1), pos), cfg.rope_theta)
+            k_new = apply_rope(k_new, jnp.full((b, 1), pos), cfg.rope_theta)
+            write_at = jnp.mod(pos, s_max) if ring else pos
+            cache_k = jax.lax.dynamic_update_slice_in_dim(
+                cache_k, k_new.astype(cache_k.dtype), write_at, axis=1)
+            cache_v = jax.lax.dynamic_update_slice_in_dim(
+                cache_v, v_new.astype(cache_v.dtype), write_at, axis=1)
+        if cross:
+            bias = jnp.zeros((b, 1, 1, s_max), jnp.float32)
+        elif ring:
+            slots = jnp.arange(s_max)[None, :]
+            k_pos = pos - jnp.mod(pos - slots, s_max)  # true position of a slot
+            ok = k_pos >= 0
+            bias = jnp.where(ok, 0.0,
+                             -1e30).astype(jnp.float32)[:, None, None, :]
+            bias = jnp.broadcast_to(bias, (b, 1, 1, s_max))
+        else:
+            q_pos = jnp.full((b, 1), pos)
+            k_pos = jnp.arange(s_max)[None, :]
+            bias = _mask_bias(q_pos, k_pos,
+                              "local" if mask_kind == "local" else "causal",
+                              cfg.window_size)[:, None]
+        out = _sdpa(q, cache_k, cache_v, bias, cfg.logit_softcap)
+        out = jnp.einsum("bshe,hed->bsd", out, params["w_o"])
+        return out, cache_k, cache_v

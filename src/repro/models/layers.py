@@ -155,6 +155,7 @@ def init_mlp(key, d_model: int, d_ff: int, dtype=jnp.bfloat16):
 
 def mlp_forward(params, x, act_name: str):
     act = activation(act_name)
-    gate = act(jnp.einsum("bsd,df->bsf", x, params["w_gate"]))
-    up = jnp.einsum("bsd,df->bsf", x, params["w_up"])
-    return jnp.einsum("bsf,fd->bsd", gate * up, params["w_down"])
+    with jax.named_scope("mlp"):
+        gate = act(jnp.einsum("bsd,df->bsf", x, params["w_gate"]))
+        up = jnp.einsum("bsd,df->bsf", x, params["w_up"])
+        return jnp.einsum("bsf,fd->bsd", gate * up, params["w_down"])

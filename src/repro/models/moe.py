@@ -12,6 +12,10 @@ Router statistics (tokens-per-expert) are returned so the CCM load balancer
 (repro.balance.expert_placement) can re-plan expert placement: experts are CCM
 *shared blocks*, per-expert token loads are task loads, and dispatch volume is
 the communication term.
+
+The layer runs under the name scope ``moe``, and its parts under ``router``,
+``dispatch``, ``experts``, ``combine`` and ``stats``, so that each op of a
+profiler trace names the part it belongs to.
 """
 from __future__ import annotations
 
@@ -67,14 +71,17 @@ def _local_moe(router_w, w_gate, w_up, w_down, x, *, cfg: ModelConfig,
 
     # FSDP all-gather of this shard's expert weights over the data axis.
     if data_size > 1:
-        w_gate = jax.lax.all_gather(w_gate, axes.data, axis=2, tiled=True)
-        w_up = jax.lax.all_gather(w_up, axes.data, axis=2, tiled=True)
-        w_down = jax.lax.all_gather(w_down, axes.data, axis=1, tiled=True)
+        with jax.named_scope("dispatch"):
+            w_gate = jax.lax.all_gather(w_gate, axes.data, axis=2, tiled=True)
+            w_up = jax.lax.all_gather(w_up, axes.data, axis=2, tiled=True)
+            w_down = jax.lax.all_gather(w_down, axes.data, axis=1, tiled=True)
 
-    logits = (x_flat.astype(jnp.float32) @ router_w)  # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_vals, top_idx = jax.lax.top_k(probs, cfg.top_k)  # (T, k)
-    top_vals = top_vals / jnp.maximum(top_vals.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("router"):
+        logits = (x_flat.astype(jnp.float32) @ router_w)  # (T, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_vals, top_idx = jax.lax.top_k(probs, cfg.top_k)  # (T, k)
+        top_vals = top_vals / jnp.maximum(top_vals.sum(-1, keepdims=True),
+                                          1e-9)
 
     cap = _capacity(cfg, t)
     act = activation(act_name)
@@ -82,29 +89,34 @@ def _local_moe(router_w, w_gate, w_up, w_down, x, *, cfg: ModelConfig,
     offset = jax.lax.axis_index(axes.model) * e_loc
     for e_local in range(e_loc):
         e_id = offset + e_local
-        w_e = jnp.where(top_idx == e_id, top_vals, 0.0).sum(-1)  # (T,)
-        sel_w, sel_i = jax.lax.top_k(jnp.where(w_e > 0, w_e, -1.0), cap)
-        valid = (sel_w > 0).astype(jnp.float32)
-        xg = x_flat[sel_i]  # (C, d)
-        g = act(xg @ w_gate[e_local])
-        u = xg @ w_up[e_local]
-        h = ((g * u) @ w_down[e_local]).astype(jnp.float32)
-        h = h * (sel_w * valid)[:, None]
-        out = out.at[sel_i].add(h)
+        with jax.named_scope("dispatch"):
+            w_e = jnp.where(top_idx == e_id, top_vals, 0.0).sum(-1)  # (T,)
+            sel_w, sel_i = jax.lax.top_k(jnp.where(w_e > 0, w_e, -1.0), cap)
+            valid = (sel_w > 0).astype(jnp.float32)
+            xg = x_flat[sel_i]  # (C, d)
+        with jax.named_scope("experts"):
+            g = act(xg @ w_gate[e_local])
+            u = xg @ w_up[e_local]
+            h = ((g * u) @ w_down[e_local]).astype(jnp.float32)
+        with jax.named_scope("combine"):
+            h = h * (sel_w * valid)[:, None]
+            out = out.at[sel_i].add(h)
 
-    out = jax.lax.psum(out, axes.model)
+    with jax.named_scope("combine"):
+        out = jax.lax.psum(out, axes.model)
 
     # Router stats: tokens-per-expert counts + Switch-style aux loss.
-    assign = jax.nn.one_hot(top_idx[:, 0], e, dtype=jnp.float32)  # top-1 frac
-    f_frac = assign.mean(0)
-    p_mean = probs.mean(0)
-    aux = e * jnp.sum(f_frac * p_mean)
-    counts = jnp.zeros((e,), jnp.float32)
-    for k in range(cfg.top_k):
-        counts = counts + jax.nn.one_hot(top_idx[:, k], e,
-                                         dtype=jnp.float32).sum(0)
-    aux = jax.lax.pmean(aux, axes.batch)
-    counts = jax.lax.psum(counts, axes.batch)
+    with jax.named_scope("stats"):
+        assign = jax.nn.one_hot(top_idx[:, 0], e, dtype=jnp.float32)  # top-1
+        f_frac = assign.mean(0)
+        p_mean = probs.mean(0)
+        aux = e * jnp.sum(f_frac * p_mean)
+        counts = jnp.zeros((e,), jnp.float32)
+        for k in range(cfg.top_k):
+            counts = counts + jax.nn.one_hot(top_idx[:, k], e,
+                                             dtype=jnp.float32).sum(0)
+        aux = jax.lax.pmean(aux, axes.batch)
+        counts = jax.lax.psum(counts, axes.batch)
     return out.reshape(b, s, d).astype(x.dtype), aux, counts
 
 
@@ -116,21 +128,23 @@ def moe_forward(params, x, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes,
         _local_moe, cfg=cfg, axes=axes, act_name=act_name,
         model_size=int(mesh.shape[axes.model]),
         data_size=int(mesh.shape[axes.data]))
-    y, aux, counts = jax.shard_map(
-        fn,
-        mesh=mesh,
-        in_specs=(
-            P(None, None),                       # router (d, E) replicated
-            P(axes.model, None, axes.data),      # w_gate (E, d, f)
-            P(axes.model, None, axes.data),      # w_up
-            P(axes.model, axes.data, None),      # w_down (E, f, d)
-            P(bspec, None, None),                # x
-        ),
-        out_specs=(P(bspec, None, None), P(), P()),
-        check_vma=False,
-    )(params["router"], params["w_gate"], params["w_up"], params["w_down"], x)
+    with jax.named_scope("moe"):
+        y, aux, counts = jax.shard_map(
+            fn,
+            mesh=mesh,
+            in_specs=(
+                P(None, None),                       # router (d, E) replicated
+                P(axes.model, None, axes.data),      # w_gate (E, d, f)
+                P(axes.model, None, axes.data),      # w_up
+                P(axes.model, axes.data, None),      # w_down (E, f, d)
+                P(bspec, None, None),                # x
+            ),
+            out_specs=(P(bspec, None, None), P(), P()),
+            check_vma=False,
+        )(params["router"], params["w_gate"], params["w_up"],
+          params["w_down"], x)
 
-    if cfg.num_shared_experts:
-        from repro.models.layers import mlp_forward
-        y = y + mlp_forward(params["shared"], x, act_name)
+        if cfg.num_shared_experts:
+            from repro.models.layers import mlp_forward
+            y = y + mlp_forward(params["shared"], x, act_name)
     return y, {"aux_loss": aux, "expert_counts": counts}

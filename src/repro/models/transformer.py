@@ -272,10 +272,11 @@ def _pack_cache(kind: str, kv):
 
 # ----------------------------------------------------------------- embedding
 def embed_tokens(params, tokens, cfg: ModelConfig):
-    x = jnp.take(params["embed"], tokens, axis=0)
-    if cfg.tie_embeddings:
-        x = x * jnp.sqrt(jnp.float32(cfg.d_model)).astype(x.dtype)
-    return x
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        if cfg.tie_embeddings:
+            x = x * jnp.sqrt(jnp.float32(cfg.d_model)).astype(x.dtype)
+        return x
 
 
 def unembed(params, x, cfg: ModelConfig):
@@ -318,22 +319,23 @@ def masked_cross_entropy(params, x, targets, cfg: ModelConfig, ctx: Ctx):
     With cfg.ce_chunk > 0 the sequence is processed in chunks so the f32
     (B, chunk, V) logits tile replaces the full (B, S, V) residency (§Perf
     memory-term optimization)."""
-    table = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    s = x.shape[1]
-    if cfg.ce_chunk and s > cfg.ce_chunk:
-        nll_total = jnp.float32(0.0)
-        count = jnp.int32(0)
-        for lo in range(0, s, cfg.ce_chunk):
-            hi = min(lo + cfg.ce_chunk, s)
-            nll, cnt = _ce_piece(x[:, lo:hi], targets[:, lo:hi], table, cfg,
-                                 ctx)
-            nll_total = nll_total + nll
-            count = count + cnt
-        denom = jnp.maximum(count, 1)
-        return nll_total / denom, denom
-    nll, cnt = _ce_piece(x, targets, table, cfg, ctx)
-    denom = jnp.maximum(cnt, 1)
-    return nll / denom, denom
+    with jax.named_scope("head"):
+        table = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        s = x.shape[1]
+        if cfg.ce_chunk and s > cfg.ce_chunk:
+            nll_total = jnp.float32(0.0)
+            count = jnp.int32(0)
+            for lo in range(0, s, cfg.ce_chunk):
+                hi = min(lo + cfg.ce_chunk, s)
+                nll, cnt = _ce_piece(x[:, lo:hi], targets[:, lo:hi], table,
+                                     cfg, ctx)
+                nll_total = nll_total + nll
+                count = count + cnt
+            denom = jnp.maximum(count, 1)
+            return nll_total / denom, denom
+        nll, cnt = _ce_piece(x, targets, table, cfg, ctx)
+        denom = jnp.maximum(cnt, 1)
+        return nll / denom, denom
 
 
 def lm_loss(params, batch, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes):

@@ -33,31 +33,32 @@ def global_norm(tree) -> jnp.ndarray:
 def adamw_update(grads, state: AdamWState, params, lr, *, b1=0.9, b2=0.95,
                  eps=1e-8, weight_decay=0.0, clip_norm=1.0):
     """Returns (new_params, new_state).  ``lr`` may be a scalar or schedule(step)."""
-    step = state.step + 1
-    if callable(lr):
-        lr = lr(step)
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-9)) \
-        if clip_norm else 1.0
+    with jax.named_scope("optimizer"):
+        step = state.step + 1
+        if callable(lr):
+            lr = lr(step)
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-9)) \
+            if clip_norm else 1.0
 
-    def upd(g, m, v, p):
-        g = g.astype(jnp.float32) * scale
-        m2 = b1 * m + (1 - b1) * g
-        v2 = b2 * v + (1 - b2) * jnp.square(g)
-        mhat = m2 / (1 - b1 ** step.astype(jnp.float32))
-        vhat = v2 / (1 - b2 ** step.astype(jnp.float32))
-        delta = mhat / (jnp.sqrt(vhat) + eps)
-        if weight_decay:
-            delta = delta + weight_decay * p.astype(jnp.float32)
-        p2 = p.astype(jnp.float32) - lr * delta
-        return p2.astype(p.dtype), m2, v2
+        def upd(g, m, v, p):
+            g = g.astype(jnp.float32) * scale
+            m2 = b1 * m + (1 - b1) * g
+            v2 = b2 * v + (1 - b2) * jnp.square(g)
+            mhat = m2 / (1 - b1 ** step.astype(jnp.float32))
+            vhat = v2 / (1 - b2 ** step.astype(jnp.float32))
+            delta = mhat / (jnp.sqrt(vhat) + eps)
+            if weight_decay:
+                delta = delta + weight_decay * p.astype(jnp.float32)
+            p2 = p.astype(jnp.float32) - lr * delta
+            return p2.astype(p.dtype), m2, v2
 
-    flat_p, treedef = jax.tree.flatten(params)
-    flat_g = treedef.flatten_up_to(grads)
-    flat_m = treedef.flatten_up_to(state.m)
-    flat_v = treedef.flatten_up_to(state.v)
-    out = [upd(g, m, v, p) for g, m, v, p in zip(flat_g, flat_m, flat_v, flat_p)]
-    new_p = treedef.unflatten([o[0] for o in out])
-    new_m = treedef.unflatten([o[1] for o in out])
-    new_v = treedef.unflatten([o[2] for o in out])
-    return new_p, AdamWState(step=step, m=new_m, v=new_v)
+        flat_p, treedef = jax.tree.flatten(params)
+        flat_g = treedef.flatten_up_to(grads)
+        flat_m = treedef.flatten_up_to(state.m)
+        flat_v = treedef.flatten_up_to(state.v)
+        out = [upd(g, m, v, p) for g, m, v, p in zip(flat_g, flat_m, flat_v, flat_p)]
+        new_p = treedef.unflatten([o[0] for o in out])
+        new_m = treedef.unflatten([o[1] for o in out])
+        new_v = treedef.unflatten([o[2] for o in out])
+        return new_p, AdamWState(step=step, m=new_m, v=new_v)
