@@ -1,19 +1,27 @@
 """GQA attention: full/sliding-window masks, logit softcap, cross-attention,
 and decode with an updatable KV cache.
 
-The jnp path here is the lowering used by the dry-run and CPU smoke tests; the
-Pallas flash kernel (repro.kernels.flash) implements the same math for TPU and
-is validated against it in tests.
+On a TPU, causal and sliding-window self-attention over a sequence that is a
+multiple of 128 runs its score-softmax-PV core on JAX's fused Pallas kernel
+(splash attention, forward and backward): the (S, S) scores never reach HBM.
+Everything else (cross-attention, the unmasked encoder, decode, ragged
+lengths, other backends) takes the jnp path ``_sdpa``, which materialises
+the scores in f32; the tests hold the kernel to it.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import LP, apply_rope, dense_init, softcap
+from repro.sharding import MeshAxes
 
 
 def init_attention(key, cfg: ModelConfig, dtype=jnp.bfloat16):
@@ -95,9 +103,103 @@ def _sdpa_chunked(q, k, v, bias, logit_cap: float, kv_chunk: int):
     return out.reshape(b, sq, h, hd).astype(v.dtype)
 
 
+# ------------------------------------------------------------ fused kernel
+def use_fused_kernel(platform: str, sq: int, sk: int, mask_kind: str,
+                     cross: bool) -> bool:
+    """Whether attention over these inputs runs on the fused kernel: TPU
+    self-attention, causal or sliding-window, over a multiple of 128."""
+    return (platform == "tpu" and not cross and sq == sk and sq % 128 == 0
+            and mask_kind in ("causal", "local"))
+
+
+def _block(seq: int) -> int:
+    """q and kv block of every kernel phase: the largest of 1024, 512, 256
+    and 128 that divides the sequence (1024 was the fastest on a v5e at
+    S=4096, head_dim 128; PERF.md)."""
+    return next(b for b in (1024, 512, 256, 128) if seq % b == 0)
+
+
+@functools.lru_cache(maxsize=32)
+def _splash_kernel(seq: int, block: int, mask_kind: str, window: int,
+                   logit_cap: float, group: int, interpret: bool):
+    """The MQA kernel for one kv head's ``group`` q heads over ``seq``
+    positions: its mask and block tables, built once per shape."""
+    if mask_kind == "causal":
+        mask = splash.CausalMask((seq, seq))
+    else:   # key k is seen by query q iff q - window < k <= q, as _mask_bias
+        mask = splash.LocalMask((seq, seq), window_size=(window - 1, 0),
+                                offset=0)
+    # one backward kernel computes dq, dk and dv together
+    blocks = splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        use_fused_bwd_kernel=True)
+    # the block tables are numpy constants, never tracers of the caller's jit
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mqa_single_device(
+            splash.MultiHeadMask([mask] * group), block_sizes=blocks,
+            attn_logits_soft_cap=logit_cap if logit_cap > 0 else None,
+            interpret=interpret)
+
+
+def _splash_local(q, k, v, *, mask_kind: str, window: int, logit_cap: float,
+                  interpret: bool):
+    """Attention core of one device's shard.  q: (B,S,H,hd) bf16, already
+    scaled; k, v: (B,S,Hkv,hd).  q head h reads kv head h // G."""
+    b, s, h, hd = q.shape
+    hkv = k.shape[2]
+    g = h // hkv
+    kernel = _splash_kernel(s, _block(s), mask_kind, window,
+                            float(logit_cap), g, interpret)
+    qt = q.reshape(b, s, hkv, g, hd).transpose(0, 2, 3, 1, 4)  # (B,Hkv,G,S,hd)
+    kt = k.transpose(0, 2, 1, 3)                                # (B,Hkv,S,hd)
+    vt = v.transpose(0, 2, 1, 3)
+    out = jax.vmap(jax.vmap(kernel))(qt, kt, vt)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, hd)
+
+
+def _splash(q, k, v, cfg: ModelConfig, mask_kind: str, mesh: Optional[Mesh],
+            axes: Optional[MeshAxes], interpret: bool):
+    """The fused core over (B,S,H,hd) q and (B,S,Hkv,hd) k, v, or None
+    where a mesh of several devices cannot split the inputs by batch and
+    heads.  The 1/sqrt(hd) scale is applied to q in f32, then q is cast
+    back to its dtype; the kernel accumulates and keeps its softmax
+    statistics in f32."""
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    fn = functools.partial(_splash_local, mask_kind=mask_kind,
+                           window=cfg.window_size,
+                           logit_cap=cfg.logit_softcap, interpret=interpret)
+    if mesh is None or mesh.size == 1:
+        return fn(q, k, v)
+    # a pallas_call is not partitioned by the compiler: run it per shard
+    n_batch = int(np.prod([mesh.shape[a] for a in axes.batch]))
+    n_model = mesh.shape[axes.model]
+    if q.shape[0] % n_batch or q.shape[2] % n_model or k.shape[2] % n_model:
+        return None
+    bspec = axes.batch if len(axes.batch) > 1 else axes.batch[0]
+    spec = P(bspec, None, axes.model, None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+def _platform(mesh: Optional[Mesh]) -> str:
+    if mesh is not None:
+        return mesh.devices.flat[0].platform
+    return jax.default_backend()
+
+
 def attention_forward_kv(params, x, cfg: ModelConfig, *, mask_kind: str,
-                         positions, kv_x=None, kv_positions=None):
+                         positions, kv_x=None, kv_positions=None,
+                         mesh: Optional[Mesh] = None,
+                         axes: Optional[MeshAxes] = None,
+                         interpret: bool = False):
     """Training/prefill attention.  ``kv_x`` set => cross-attention.
+
+    ``mesh``/``axes`` are the activations' mesh, for the fused kernel's
+    per-shard call.  ``interpret`` runs the fused kernel in the Pallas
+    interpreter whatever the backend (tests on the CPU).  The kernel masks
+    by index: it takes ``positions`` to be 0..S-1, as every caller passes.
 
     Returns (out, k, v) so prefill can populate the KV cache for free.
     """
@@ -112,13 +214,19 @@ def attention_forward_kv(params, x, cfg: ModelConfig, *, mask_kind: str,
             kv_pos = positions
         else:
             kv_pos = kv_positions
-        bias = _mask_bias(positions, kv_pos, mask_kind,
-                          cfg.window_size)[:, None]
-        if cfg.attn_kv_chunk and k.shape[1] > cfg.attn_kv_chunk:
-            out = _sdpa_chunked(q, k, v, bias, cfg.logit_softcap,
-                                cfg.attn_kv_chunk)
-        else:
-            out = _sdpa(q, k, v, bias, cfg.logit_softcap)
+        out = None
+        platform = "tpu" if interpret else _platform(mesh)
+        if use_fused_kernel(platform, q.shape[1], k.shape[1], mask_kind,
+                            kv_x is not None):
+            out = _splash(q, k, v, cfg, mask_kind, mesh, axes, interpret)
+        if out is None:
+            bias = _mask_bias(positions, kv_pos, mask_kind,
+                              cfg.window_size)[:, None]
+            if cfg.attn_kv_chunk and k.shape[1] > cfg.attn_kv_chunk:
+                out = _sdpa_chunked(q, k, v, bias, cfg.logit_softcap,
+                                    cfg.attn_kv_chunk)
+            else:
+                out = _sdpa(q, k, v, bias, cfg.logit_softcap)
         return jnp.einsum("bshe,hed->bsd", out, params["w_o"]), k, v
 
 

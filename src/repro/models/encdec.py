@@ -105,7 +105,8 @@ def run_decoder(params, tokens, enc_out, cfg: ModelConfig, ctx: Ctx,
         blk = p["b0"]
         h = rms_norm(x, blk["norm_self"], cfg.norm_eps)
         a, sk, sv = attn.attention_forward_kv(
-            blk["self_attn"], h, cfg, mask_kind="causal", positions=positions)
+            blk["self_attn"], h, cfg, mask_kind="causal", positions=positions,
+            mesh=ctx.mesh, axes=ctx.axes)
         x = x + a
         h = rms_norm(x, blk["norm_cross"], cfg.norm_eps)
         a, ck, cv = attn.attention_forward_kv(

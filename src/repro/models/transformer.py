@@ -123,7 +123,8 @@ def block_train(p, kind: str, x, positions, ctx: Ctx, return_kv=False):
         mask_kind = "local" if kind == BLOCK_LOCAL else "causal"
         h = rms_norm(x, p["norm_attn"], cfg.norm_eps)
         a, k_c, v_c = attn.attention_forward_kv(
-            p["attn"], h, cfg, mask_kind=mask_kind, positions=positions)
+            p["attn"], h, cfg, mask_kind=mask_kind, positions=positions,
+            mesh=ctx.mesh, axes=ctx.axes)
         if return_kv:
             kv = (k_c, v_c)
         x = x + a

@@ -2,6 +2,7 @@
 HLO's ``op_name``s carry each scope the configuration reaches, on forward
 ops and on the backward (``transpose(...)``) ops.  The benchmark reads the
 device time of each scope from these names (``bench/scopes.py``)."""
+import dataclasses
 import re
 
 import jax
@@ -11,6 +12,8 @@ import pytest
 from repro import configs
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import abstract_opt, abstract_params, make_train_step
+from repro.models import attention as attn
+from repro.models.layers import split_lp_tree
 from repro.models.model import build_model
 
 MOE_SCOPES = ("embed", "attn", "moe", "moe/router", "moe/dispatch",
@@ -74,3 +77,46 @@ def test_segments_are_whole():
     assert _carries("jit(s)/transpose(jvp(head))/dot_general", "head")
     assert not _carries("jit(s)/moe_x/experts/dot_general", "moe/experts")
     assert not _carries("jit(s)/moe/stats/experts/mul", "moe/experts")
+
+
+# the fused attention kernel's phases, by the kernel names splash gives them
+KERNEL_PHASES = {
+    "forward": ("splash_mqa_fwd", lambda n: "transpose(" not in n),
+    "recomputed": ("splash_mqa_fwd",
+                   lambda n: "rematted_computation" in n),
+    # one fused backward kernel computes dq, dk and dv
+    "backward": ("splash_mqa_dkv", lambda n: "transpose(" in n),
+}
+
+
+@pytest.fixture(scope="module")
+def kernel_names():
+    """Op names of one attention layer on the fused kernel (in the Pallas
+    interpreter), under full remat, value and gradient, as in the train
+    step."""
+    cfg = dataclasses.replace(configs.get_smoke_config("qwen3-moe-30b-a3b"),
+                              d_model=256, num_heads=8, num_kv_heads=2,
+                              head_dim=128)
+    p = split_lp_tree(attn.init_attention(jax.random.key(0), cfg))[0]
+    b, s = 2, 256
+    x = jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.bfloat16)
+    pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+
+    def loss(p, x):
+        out, _, _ = attn.attention_forward_kv(p, x, cfg, mask_kind="causal",
+                                              positions=pos, interpret=True)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1))
+                   ).lower(p, x).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+@pytest.mark.parametrize("phase", list(KERNEL_PHASES))
+def test_the_fused_kernel_carries_attn(kernel_names, phase):
+    kernel, in_phase = KERNEL_PHASES[phase]
+    ops = [n for n in kernel_names if in_phase(n)
+           and any(t.startswith(kernel) for t in re.split(r"[/()]", n))]
+    assert ops, phase
+    scope = "rematted_computation/attn" if phase == "recomputed" else "attn"
+    assert all(_carries(n, scope) for n in ops), phase

@@ -2,10 +2,12 @@
 
 The TPU compiler refuses what the Pallas interpreter and XLA:CPU accept:
 blocks off the (8, 128) tiling, non-integer iotas, programs that do not
-fit the chip.  These tests make it look at the scorer kernel and at one
-full-width qwen3-moe expert layer.  The topology is described inside a
-fixture, never at import, so that every test worker collects the same
-tests and only the worker that runs this file loads the TPU library.
+fit the chip.  These tests make it look at the scorer kernel, at one
+full-width qwen3-moe expert layer and at the fused attention path at the
+train cell's shapes, on one chip and on the 2x2 mesh.  The topology is
+described inside a fixture, never at import, so that every test worker
+collects the same tests and only the worker that runs this file loads the
+TPU library.
 """
 import os
 
@@ -13,14 +15,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import AxisType, Mesh, SingleDeviceSharding
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro import configs
 from repro.kernels.ccm_scorer.kernel import score_tiles_fwd
 from repro.kernels.ccm_scorer.layout import N_AV, N_OUT, N_PM, N_SC
+from repro.models import attention as attn_lib
 from repro.models import moe as moe_lib
 from repro.models.layers import split_lp_tree
-from repro.sharding import MeshAxes
+from repro.sharding import MeshAxes, specs_for_lp_tree
 
 
 @pytest.fixture(scope="module")
@@ -95,3 +99,57 @@ def test_qwen3_expert_layer_compiles_for_v5e(topo, no_compile_cache):
     weights = 3 * cfg.num_experts * cfg.d_model * cfg.moe_d_ff * 2
     assert mem.argument_size_in_bytes >= weights
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def _attention_train_compile(mesh, batch):
+    """Forward, remat's recompute and backward of one qwen3-moe-30b-a3b
+    attention layer (32 q heads, 4 kv heads, head_dim 128) at seq 4096,
+    batch-sharded on ``mesh``."""
+    cfg = configs.get_config("qwen3-moe-30b-a3b")
+    axes = MeshAxes.for_mesh(mesh)
+    seq = 4096
+    lp = jax.eval_shape(lambda k: attn_lib.init_attention(k, cfg),
+                        jax.random.key(0))
+    specs = specs_for_lp_tree(mesh, axes, lp)
+    params = jax.tree.map(
+        lambda s, spec: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, spec)),
+        split_lp_tree(lp)[0], specs)
+    x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+    pos = jnp.broadcast_to(jnp.arange(seq)[None], (batch, seq))
+
+    def loss(p, x):
+        out, _, _ = attn_lib.attention_forward_kv(
+            p, x, cfg, mask_kind="causal", positions=pos, mesh=mesh,
+            axes=axes)
+        return out.astype(jnp.float32).sum()
+
+    step = jax.jit(jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1)))
+    return step.lower(params, x).compile()
+
+
+def test_attention_takes_the_kernel_on_one_chip(topo, no_compile_cache):
+    """The train cell's attention at (3, 4096, 32/4, 128) on one v5e runs
+    on the fused kernel: its temporaries stay under 1 GB, where the
+    materialised (3, 4, 8, 4096, 4096) f32 scores need 6.7 GB."""
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    compiled = _attention_train_compile(mesh, 3)
+    text = compiled.as_text()
+    # the forward, remat's recompute of it and the fused backward
+    assert text.count("tpu_custom_call") == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_attention_kernel_is_sharded_on_the_2x2_mesh(topo, no_compile_cache):
+    """On (data 2, model 2) the kernel runs per shard under ``shard_map``:
+    batch on ``data``, the 32 q and 4 kv heads on ``model``."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    compiled = _attention_train_compile(mesh, 4)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    # each chip holds a quarter of the work: 2 of the 4 sequences, 16 of
+    # the 32 heads
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
