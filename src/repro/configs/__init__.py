@@ -1,6 +1,7 @@
 """Config registry: ``get_config(arch)`` / ``get_smoke_config(arch)``.
 
-All ten assigned architectures plus the paper's own application config
+The ten assigned architectures, Moonlight-16B-A3B (latent attention and
+sigmoid-routed shared-expert MoE), and the paper's own application config
 (``gemma-assembly``, see repro.assembly).
 """
 from __future__ import annotations
@@ -21,6 +22,7 @@ from repro.configs import (  # noqa: E402
     llama3_2_3b,
     llama4_scout_17b_a16e,
     llava_next_mistral_7b,
+    moonlight_16b_a3b,
     qwen3_moe_30b_a3b,
     recurrentgemma_9b,
     rwkv6_7b,
@@ -40,6 +42,7 @@ _MODULES = {
     "llama4-scout-17b-a16e": llama4_scout_17b_a16e,
     "rwkv6-7b": rwkv6_7b,
     "recurrentgemma-9b": recurrentgemma_9b,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
 }
 
 ARCH_IDS = tuple(_MODULES)

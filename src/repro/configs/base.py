@@ -61,13 +61,37 @@ class ModelConfig:
     logit_softcap: float = 0.0  # gemma2 attention-logit soft cap
     final_softcap: float = 0.0  # gemma2 final-logit soft cap
     rope_theta: float = 10000.0
+    # latent attention (attn_type "mla", DeepSeek-V2/V3): q from x, k and v
+    # up-projected from a normed kv_lora_rank latent; one rope key of
+    # qk_rope_head_dim shared by all heads
+    attn_type: str = "gqa"      # gqa | mla
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- MoE ----------------------------------------------------------------
     num_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0           # per-expert hidden dim
     num_shared_experts: int = 0
+    shared_d_ff: int = 0        # the shared block's width; 0 -> d_ff x shared
     capacity_factor: float = 1.25
+    # softmax: the top-k of softmax(logits); sigmoid: the top-k of
+    # sigmoid(logits) + router_bias, weighed by the sigmoid alone
+    router_scoring: str = "softmax"
+    # > 0: a router_bias that each step moves by this rate against the
+    # routed load (no gradient, no optimizer)
+    router_bias_rate: float = 0.0
+    # the top-k weights, renormalised to sum 1, times this
+    routed_scaling: float = 1.0
+    # the experts this layer holds (0 -> all), from first_held_expert on;
+    # the router still scores all num_experts
+    experts_held: int = 0
+    first_held_expert: int = 0
+    aux_loss: str = "switch"    # switch (top-1 share) | sequence (DeepSeek-V3)
+    aux_loss_weight: float = 0.01
+    first_dense_layers: int = 0  # dense (attn) layers before the pattern
 
     # --- recurrent families ---------------------------------------------------
     rwkv_head_dim: int = 64
@@ -111,7 +135,9 @@ class ModelConfig:
 
     def __post_init__(self):
         if self.head_dim == 0:
-            object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+            object.__setattr__(self, "head_dim", (
+                self.qk_nope_head_dim + self.qk_rope_head_dim
+                if self.attn_type == "mla" else self.d_model // self.num_heads))
         if self.arch_type == "encdec" and self.num_decoder_layers == 0:
             object.__setattr__(self, "num_decoder_layers", self.num_layers)
         assert self.num_heads % max(self.num_kv_heads, 1) == 0, self.name
@@ -124,7 +150,18 @@ class ModelConfig:
     def layer_kinds(self, num_layers: Optional[int] = None) -> Tuple[str, ...]:
         n = num_layers if num_layers is not None else self.num_layers
         p = self.block_pattern
-        return tuple(p[i % len(p)] for i in range(n))
+        lead = min(self.first_dense_layers, n)
+        return (BLOCK_ATTN,) * lead + tuple(p[i % len(p)]
+                                            for i in range(n - lead))
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the shared-expert block (all shared experts as one)."""
+        return self.shared_d_ff or self.d_ff * self.num_shared_experts
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def is_moe(self) -> bool:
@@ -149,7 +186,14 @@ class ModelConfig:
             kinds = kinds + self.layer_kinds(self.num_decoder_layers)
         for kind in kinds:
             total += 2 * d  # pre-norms (approximation: 2 norms / block)
-            if kind in (BLOCK_ATTN, BLOCK_LOCAL, BLOCK_MOE):
+            if kind in (BLOCK_ATTN, BLOCK_LOCAL, BLOCK_MOE) and \
+                    self.attn_type == "mla":
+                h, r = self.num_heads, self.kv_lora_rank
+                rope, nope = self.qk_rope_head_dim, self.qk_nope_head_dim
+                total += d * h * (nope + rope) + d * (r + rope) + r
+                total += r * h * (nope + self.v_head_dim)
+                total += h * self.v_head_dim * d
+            elif kind in (BLOCK_ATTN, BLOCK_LOCAL, BLOCK_MOE):
                 total += d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd
                 total += self.num_heads * hd * d
                 if self.arch_type == "encdec":
@@ -159,7 +203,7 @@ class ModelConfig:
             if kind == BLOCK_MOE:
                 total += d * self.num_experts  # router
                 total += self.num_experts * 3 * d * self.moe_d_ff
-                total += self.num_shared_experts * 3 * d * self.d_ff
+                total += 3 * d * self.shared_width
             elif kind == BLOCK_RWKV:
                 total += 4 * d * d + d * d  # r,k,v,g,o projections (approx)
                 total += 3 * d * self.d_ff // 1  # channel mix (k,v,r)

@@ -17,6 +17,8 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models.layers import split_lp_tree
 from repro.models.model import (Model, batch_specs, build_model, cache_specs,
                                 decode_token_specs)
+from repro.models.moe import is_router_state
+from repro.models.transformer import update_router_bias
 from repro.optim import adamw_init, adamw_update, warmup_cosine
 from repro.sharding import MeshAxes, shardings_for_lp_tree
 
@@ -62,13 +64,21 @@ def abstract_opt(params_sds, param_shardings):
 
 def make_train_step(model: Model, *, lr=3e-4, weight_decay=0.1,
                     warmup_steps=100, total_steps=10000):
+    """The step: gradients, AdamW on every parameter but the router state
+    (``moe.is_router_state``), then, where the config has a bias rate, the
+    router bias moved by the step's own routed counts."""
     schedule = warmup_cosine(lr, warmup_steps, total_steps)
+    cfg = model.cfg
 
     def train_step(params, opt_state, batch):
         (loss, metrics), grads = jax.value_and_grad(
             model.loss_fn, has_aux=True)(params, batch)
         new_params, new_opt = adamw_update(
-            grads, opt_state, params, schedule, weight_decay=weight_decay)
+            grads, opt_state, params, schedule, weight_decay=weight_decay,
+            skip=is_router_state)
+        if cfg.router_bias_rate:
+            new_params = update_router_bias(new_params,
+                                            metrics["expert_counts"], cfg)
         metrics = dict(metrics)
         metrics["loss"] = loss
         return new_params, new_opt, metrics

@@ -90,9 +90,22 @@ def train_loop(cfg, mesh, *, steps: int, seq_len: int, global_batch: int,
 
 
 def _permute_experts(params, opt_state, perms, cfg):
-    """Apply the per-period slot permutations ``perms`` (periods, E) to the
-    expert weights and router of every MoE block, and to their AdamW
-    moments, so each moment stays with the weight it belongs to."""
+    """Apply the per-period slot permutations ``perms`` (periods, held) to
+    the held expert weights of every MoE block, to the router's columns and
+    router bias of the held experts, and to their AdamW moments, so each
+    moment stays with the weight it belongs to."""
+    lo = cfg.first_held_expert
+    hi = lo + cfg.held_experts
+
+    def take(leaf, axis, cols=None):
+        def one(sl, p):
+            if cols is None:
+                return jnp.take(sl, p, axis=axis)
+            idx = jnp.arange(sl.shape[axis]).at[cols[0]:cols[1]].set(
+                p + cols[0])
+            return jnp.take(sl, idx, axis=axis)
+        return jax.vmap(one)(leaf, perms)
+
     def one_tree(tree):
         scan = dict(tree["scan"])
         for i, kind in enumerate(cfg.block_pattern):
@@ -100,11 +113,11 @@ def _permute_experts(params, opt_state, perms, cfg):
                 continue
             blk = dict(scan[f"b{i}"])
             moe = dict(blk["moe"])
-            for name, axis in (("w_gate", 0), ("w_up", 0), ("w_down", 0),
-                               ("router", 1)):
-                moe[name] = jax.vmap(
-                    lambda sl, p, axis=axis: jnp.take(sl, p, axis=axis))(
-                        moe[name], perms)
+            for name in ("w_gate", "w_up", "w_down"):
+                moe[name] = take(moe[name], 0)
+            moe["router"] = take(moe["router"], 1, (lo, hi))
+            if "router_bias" in moe:
+                moe["router_bias"] = take(moe["router_bias"], 0, (lo, hi))
             blk["moe"] = moe
             scan[f"b{i}"] = blk
         out = dict(tree)
@@ -115,18 +128,40 @@ def _permute_experts(params, opt_state, perms, cfg):
                                                 v=one_tree(opt_state.v))
 
 
+@functools.lru_cache(maxsize=8)
+def _permute_program(cfg, shardings, treedef):
+    """The jitted permutation for one config and placement, built once, so
+    that a replan after the first compiles nothing."""
+    return jax.jit(functools.partial(_permute_experts, cfg=cfg),
+                   out_shardings=jax.tree_util.tree_unflatten(
+                       treedef, shardings),
+                   donate_argnums=(0, 1))
+
+
+def apply_expert_permutation(params, opt_state, perms, cfg):
+    """``_permute_experts`` in one program that keeps each leaf's sharding,
+    so the experts stay spread over the model axis; built once per config
+    and placement."""
+    leaves, treedef = jax.tree.flatten((params, opt_state))
+    return _permute_program(cfg, tuple(a.sharding for a in leaves), treedef)(
+        params, opt_state, jnp.asarray(perms))
+
+
 def rebalance_experts(params, opt_state, counts, cfg, mesh, *,
                       hbm_budget_bytes: Optional[float] = None):
     """CCM-LB plan -> per-layer slot permutation applied to live params and
     to the optimizer state.  Returns ``(params, opt_state, plan)``; ``plan``
     is ``None`` when nothing was applied.
 
-    The plan's per-device budget is ``hbm_budget_bytes`` or, when that is
-    ``None``, the limit the device reports; a backend that reports none
-    needs it passed."""
+    ``counts`` (periods, num_experts) are the router's counts of every
+    expert; the plan places the experts this layer holds
+    (``cfg.experts_held``) over the model axis.  The plan's per-device
+    budget is ``hbm_budget_bytes`` or, when that is ``None``, the limit the
+    device reports; a backend that reports none needs it passed."""
     n_model = int(mesh.shape["model"])
     n_dev = max(n_model, 1)
-    if n_dev == 1 or cfg.num_experts % n_dev:
+    held = cfg.held_experts
+    if n_dev == 1 or held % n_dev:
         return params, opt_state, None
     if hbm_budget_bytes is None:
         stats = mesh.devices.flat[0].memory_stats() or {}
@@ -134,21 +169,17 @@ def rebalance_experts(params, opt_state, counts, cfg, mesh, *,
             raise ValueError("the backend reports no device memory limit: "
                              "pass hbm_budget_bytes")
         hbm_budget_bytes = float(stats["bytes_limit"])
+    lo = cfg.first_held_expert
     with jax.profiler.TraceAnnotation("ccm_lb.plan"):
         plan = plan_expert_placement(
-            counts, cfg, n_dev,
+            np.asarray(counts)[:, lo:lo + held], cfg, n_dev,
             hbm_budget_bytes=hbm_budget_bytes,
             rank_speed=None)
     if plan.max_work_after >= plan.max_work_before:
         return params, opt_state, None
-    # apply every period's permutation in one program that keeps each
-    # leaf's sharding, so the experts stay spread over the model axis
-    shardings = jax.tree.map(lambda a: a.sharding, (params, opt_state))
     with jax.profiler.TraceAnnotation("rebalance.permute"):
-        params, opt_state = jax.jit(
-            functools.partial(_permute_experts, cfg=cfg),
-            out_shardings=shardings, donate_argnums=(0, 1))(
-                params, opt_state, jnp.asarray(plan.permutations))
+        params, opt_state = apply_expert_permutation(
+            params, opt_state, plan.permutations, cfg)
     print(f"[ccm-lb] expert re-placement: imbalance "
           f"{plan.imbalance_before:.3f} -> {plan.imbalance_after:.3f} "
           f"(replication suggested on {plan.replicated_blocks} blocks)",

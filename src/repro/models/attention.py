@@ -1,5 +1,11 @@
-"""GQA attention: full/sliding-window masks, logit softcap, cross-attention,
-and decode with an updatable KV cache.
+"""GQA and latent (MLA) attention: full/sliding-window masks, logit softcap,
+cross-attention, and decode with an updatable KV cache (GQA only).
+
+MLA (DeepSeek-V2/V3) projects q from x, and k and v from a normed latent
+of ``kv_lora_rank`` (scope ``latent``); q and k carry ``qk_nope_head_dim``
+plain and ``qk_rope_head_dim`` rotary columns, the rotary key one for all
+heads, and v has ``v_head_dim``.  From there it runs the same core as GQA,
+with one kv head per q head.
 
 On a TPU, causal and sliding-window self-attention over a sequence that is a
 multiple of 128 runs its score-softmax-PV core on JAX's fused Pallas kernel
@@ -20,11 +26,14 @@ from jax.experimental.pallas.ops.tpu import splash_attention as splash
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import LP, apply_rope, dense_init, softcap
+from repro.models.layers import (LP, apply_rope, dense_init, rms_norm,
+                                 softcap, zeros_init)
 from repro.sharding import MeshAxes
 
 
 def init_attention(key, cfg: ModelConfig, dtype=jnp.bfloat16):
+    if cfg.attn_type == "mla":
+        return init_mla(key, cfg, dtype)
     kq, kk, kv, ko = jax.random.split(key, 4)
     d, h, hkv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     return {
@@ -34,6 +43,45 @@ def init_attention(key, cfg: ModelConfig, dtype=jnp.bfloat16):
         "w_o": dense_init(ko, (h, hd, d), ("heads", "head_dim", "embed"),
                           in_axis=(0, 1), dtype=dtype),
     }
+
+
+def init_mla(key, cfg: ModelConfig, dtype=jnp.bfloat16):
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "w_q": dense_init(kq, (d, h, nope + rope),
+                          ("embed", "heads", "head_dim"), dtype=dtype),
+        "w_kv_a": dense_init(ka, (d, r + rope), ("embed", "lora"),
+                             dtype=dtype),
+        "kv_norm": zeros_init((r,), ("lora",), dtype=jnp.float32),
+        "w_kv_b": dense_init(kb, (r, h, nope + vd),
+                             ("lora", "heads", "head_dim"), dtype=dtype),
+        "w_o": dense_init(ko, (h, vd, d), ("heads", "head_dim", "embed"),
+                          in_axis=(0, 1), dtype=dtype),
+    }
+
+
+def mla_qkv(params, x, positions, cfg: ModelConfig):
+    """q, k: (B,S,H,nope+rope), v: (B,S,H,v_head_dim) of latent attention.
+    The rotary key is one per position, broadcast to every head."""
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dhe->bshe", x, params["w_q"])
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope(q[..., nope:], positions, cfg.rope_theta)],
+        -1)
+    with jax.named_scope("latent"):
+        ckv = jnp.einsum("bsd,dr->bsr", x, params["w_kv_a"])
+        c = rms_norm(ckv[..., :r], params["kv_norm"], cfg.norm_eps)
+        k_pe = apply_rope(ckv[..., None, r:], positions, cfg.rope_theta)
+        kv = jnp.einsum("bsr,rhe->bshe", c, params["w_kv_b"])
+        h = kv.shape[2]
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_pe, k_pe.shape[:2] + (h, k_pe.shape[-1]))],
+            -1)
+        v = kv[..., nope:]
+    return q, k, v
 
 
 def _mask_bias(q_pos, k_pos, kind: str, window: int):
@@ -52,7 +100,8 @@ def _mask_bias(q_pos, k_pos, kind: str, window: int):
 
 
 def _sdpa(q, k, v, bias, logit_cap: float):
-    """q: (B,Sq,H,hd)  k,v: (B,Sk,Hkv,hd)  bias: broadcastable (B,1,Sq,Sk)."""
+    """q, k: (B,Sq|Sk,H|Hkv,hd)  v: (B,Sk,Hkv,hd_v)  bias: broadcastable
+    (B,1,Sq,Sk)."""
     b, sq, h, hd = q.shape
     hkv = k.shape[2]
     g = h // hkv
@@ -63,7 +112,7 @@ def _sdpa(q, k, v, bias, logit_cap: float):
     scores = scores + bias[:, :, None, :, :]
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(b, sq, h, hd)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def _sdpa_chunked(q, k, v, bias, logit_cap: float, kv_chunk: int):
@@ -79,7 +128,7 @@ def _sdpa_chunked(q, k, v, bias, logit_cap: float, kv_chunk: int):
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     m = jnp.full((b, hkv, g, sq, 1), -1e30, jnp.float32)
     l = jnp.zeros((b, hkv, g, sq, 1), jnp.float32)
-    acc = jnp.zeros((b, hkv, g, sq, hd), jnp.float32)
+    acc = jnp.zeros((b, hkv, g, sq, v.shape[-1]), jnp.float32)
     n_chunks = (sk + kv_chunk - 1) // kv_chunk
     for ci in range(n_chunks):
         lo = ci * kv_chunk
@@ -100,7 +149,7 @@ def _sdpa_chunked(q, k, v, bias, logit_cap: float, kv_chunk: int):
         m = m_new
     out = acc / jnp.where(l == 0.0, 1.0, l)
     out = jnp.moveaxis(out, 3, 1)  # (b, sq, hkv, g, hd)
-    return out.reshape(b, sq, h, hd).astype(v.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(v.dtype)
 
 
 # ------------------------------------------------------------ fused kernel
@@ -145,7 +194,8 @@ def _splash_kernel(seq: int, block: int, mask_kind: str, window: int,
 def _splash_local(q, k, v, *, mask_kind: str, window: int, logit_cap: float,
                   interpret: bool):
     """Attention core of one device's shard.  q: (B,S,H,hd) bf16, already
-    scaled; k, v: (B,S,Hkv,hd).  q head h reads kv head h // G."""
+    scaled; k: (B,S,Hkv,hd), v: (B,S,Hkv,hd_v).  q head h reads kv head
+    h // G."""
     b, s, h, hd = q.shape
     hkv = k.shape[2]
     g = h // hkv
@@ -155,7 +205,7 @@ def _splash_local(q, k, v, *, mask_kind: str, window: int, logit_cap: float,
     kt = k.transpose(0, 2, 1, 3)                                # (B,Hkv,S,hd)
     vt = v.transpose(0, 2, 1, 3)
     out = jax.vmap(jax.vmap(kernel))(qt, kt, vt)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, hd)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, v.shape[-1])
 
 
 def _splash(q, k, v, cfg: ModelConfig, mask_kind: str, mesh: Optional[Mesh],
@@ -205,28 +255,33 @@ def attention_forward_kv(params, x, cfg: ModelConfig, *, mask_kind: str,
     """
     with jax.named_scope("attn"):
         kv_in = x if kv_x is None else kv_x
-        q = jnp.einsum("bsd,dhe->bshe", x, params["w_q"])
-        k = jnp.einsum("bsd,dhe->bshe", kv_in, params["w_k"])
-        v = jnp.einsum("bsd,dhe->bshe", kv_in, params["w_v"])
-        if kv_x is None:  # self-attention -> RoPE
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+        if cfg.attn_type == "mla":
+            q, k, v = mla_qkv(params, x, positions, cfg)
             kv_pos = positions
         else:
-            kv_pos = kv_positions
-        out = None
-        platform = "tpu" if interpret else _platform(mesh)
-        if use_fused_kernel(platform, q.shape[1], k.shape[1], mask_kind,
-                            kv_x is not None):
-            out = _splash(q, k, v, cfg, mask_kind, mesh, axes, interpret)
-        if out is None:
-            bias = _mask_bias(positions, kv_pos, mask_kind,
-                              cfg.window_size)[:, None]
-            if cfg.attn_kv_chunk and k.shape[1] > cfg.attn_kv_chunk:
-                out = _sdpa_chunked(q, k, v, bias, cfg.logit_softcap,
-                                    cfg.attn_kv_chunk)
+            q = jnp.einsum("bsd,dhe->bshe", x, params["w_q"])
+            k = jnp.einsum("bsd,dhe->bshe", kv_in, params["w_k"])
+            v = jnp.einsum("bsd,dhe->bshe", kv_in, params["w_v"])
+            if kv_x is None:  # self-attention -> RoPE
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k = apply_rope(k, positions, cfg.rope_theta)
+                kv_pos = positions
             else:
-                out = _sdpa(q, k, v, bias, cfg.logit_softcap)
+                kv_pos = kv_positions
+        with jax.named_scope("core"):
+            out = None
+            platform = "tpu" if interpret else _platform(mesh)
+            if use_fused_kernel(platform, q.shape[1], k.shape[1], mask_kind,
+                                kv_x is not None):
+                out = _splash(q, k, v, cfg, mask_kind, mesh, axes, interpret)
+            if out is None:
+                bias = _mask_bias(positions, kv_pos, mask_kind,
+                                  cfg.window_size)[:, None]
+                if cfg.attn_kv_chunk and k.shape[1] > cfg.attn_kv_chunk:
+                    out = _sdpa_chunked(q, k, v, bias, cfg.logit_softcap,
+                                        cfg.attn_kv_chunk)
+                else:
+                    out = _sdpa(q, k, v, bias, cfg.logit_softcap)
         return jnp.einsum("bshe,hed->bsd", out, params["w_o"]), k, v
 
 

@@ -13,9 +13,17 @@ Router statistics (tokens-per-expert) are returned so the CCM load balancer
 *shared blocks*, per-expert token loads are task loads, and dispatch volume is
 the communication term.
 
+A layer may hold only some of the experts (``cfg.experts_held`` from
+``cfg.first_held_expert``): it still routes over all ``num_experts``,
+computes the part of the output that its own experts give and counts the
+tokens routed to every expert.  The router scores by softmax, or by sigmoid
+with a ``router_bias`` that picks the experts but does not weigh them
+(DeepSeek-V3's aux-loss-free balancing; the train step moves the bias
+against the routed load, see ``update_router_bias``).
+
 The layer runs under the name scope ``moe``, and its parts under ``router``,
-``dispatch``, ``experts``, ``combine`` and ``stats``, so that each op of a
-profiler trace names the part it belongs to.
+``dispatch``, ``experts``, ``combine``, ``shared`` and ``stats``, so that
+each op of a profiler trace names the part it belongs to.
 """
 from __future__ import annotations
 
@@ -26,15 +34,22 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import activation, dense_init
+from repro.models.layers import activation, dense_init, zeros_init
 from repro.sharding import MeshAxes
+
+
+def is_router_state(path) -> bool:
+    """Whether the parameter leaf at key path ``path`` is the router bias,
+    which the train step moves and no gradient or optimizer does."""
+    return getattr(path[-1], "key", None) == "router_bias"
 
 
 def init_moe(key, cfg: ModelConfig, dtype=jnp.bfloat16):
     kr, k1, k2, k3, ks = jax.random.split(key, 5)
-    d, e, f = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    d, e, f = cfg.d_model, cfg.held_experts, cfg.moe_d_ff
     params = {
-        "router": dense_init(kr, (d, e), ("embed", None), dtype=jnp.float32),
+        "router": dense_init(kr, (d, cfg.num_experts), ("embed", None),
+                             dtype=jnp.float32),
         "w_gate": dense_init(k1, (e, d, f), ("expert", "embed", "expert_mlp"),
                              in_axis=1, dtype=dtype),
         "w_up": dense_init(k2, (e, d, f), ("expert", "embed", "expert_mlp"),
@@ -42,10 +57,12 @@ def init_moe(key, cfg: ModelConfig, dtype=jnp.bfloat16):
         "w_down": dense_init(k3, (e, f, d), ("expert", "expert_mlp", "embed"),
                              in_axis=1, dtype=dtype),
     }
+    if cfg.router_scoring == "sigmoid":
+        params["router_bias"] = zeros_init((cfg.num_experts,), (None,),
+                                           dtype=jnp.float32)
     if cfg.num_shared_experts:
         from repro.models.layers import init_mlp
-        params["shared"] = init_mlp(ks, d, cfg.d_ff * cfg.num_shared_experts,
-                                    dtype=dtype)
+        params["shared"] = init_mlp(ks, d, cfg.shared_width, dtype=dtype)
     return params
 
 
@@ -55,19 +72,56 @@ def _capacity(cfg: ModelConfig, tokens: int) -> int:
     return max(1, min(c, tokens))
 
 
-def _local_moe(router_w, w_gate, w_up, w_down, x, *, cfg: ModelConfig,
+def _route(x_flat, router_w, bias, cfg: ModelConfig):
+    """(top_vals, top_idx, scores): the chosen experts' weights and ids
+    (T, k), and the per-token scores the balance loss reads (T, E)."""
+    logits = (x_flat.astype(jnp.float32) @ router_w)  # (T, E)
+    if cfg.router_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_idx = jax.lax.top_k(scores + bias, cfg.top_k)
+        top_vals = jnp.take_along_axis(scores, top_idx, axis=-1)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        top_vals, top_idx = jax.lax.top_k(scores, cfg.top_k)  # (T, k)
+    top_vals = top_vals / jnp.maximum(top_vals.sum(-1, keepdims=True), 1e-9)
+    if cfg.routed_scaling != 1.0:
+        top_vals = top_vals * cfg.routed_scaling
+    return top_vals, top_idx, scores
+
+
+def _aux_loss(top_idx, scores, cfg: ModelConfig, batch: int):
+    """``switch``: experts x the sum over experts of the top-1 share times
+    the mean score.  ``sequence`` (DeepSeek-V3, arXiv:2412.19437 2.1.2):
+    per sequence, the sum over experts of f_i (experts / (k x S) x the
+    tokens routed to i) times P_i (the mean of the scores normalised over
+    the experts), averaged over the sequences."""
+    e = cfg.num_experts
+    if cfg.aux_loss == "sequence":
+        t, k = top_idx.shape
+        s = t // batch
+        chosen = jax.nn.one_hot(top_idx, e, dtype=jnp.float32).sum(1)
+        f = chosen.reshape(batch, s, e).sum(1) * (e / (k * s))
+        p = scores / jnp.maximum(scores.sum(-1, keepdims=True), 1e-9)
+        p = p.reshape(batch, s, e).mean(1)
+        return jnp.mean(jnp.sum(f * p, -1))
+    assign = jax.nn.one_hot(top_idx[:, 0], e, dtype=jnp.float32)  # top-1
+    return e * jnp.sum(assign.mean(0) * scores.mean(0))
+
+
+def _local_moe(router_w, bias, w_gate, w_up, w_down, x, *, cfg: ModelConfig,
                axes: MeshAxes, act_name: str, model_size: int, data_size: int):
     """Per-device body under shard_map.
 
     x: (B_loc, S, d) — identical across the model axis, sharded over batch.
-    w_*: (E_loc, d, f_loc) — expert-sharded over model, fsdp over data.
+    w_*: (E_loc, d, f_loc) — the held experts sharded over model, fsdp over
+    data; this shard holds experts ``offset .. offset + E_loc - 1``.
     """
     b, s, d = x.shape
     t = b * s
     x_flat = x.reshape(t, d)
     e = cfg.num_experts
-    e_loc = e // model_size
-    assert e % model_size == 0, (e, model_size)
+    e_loc = cfg.held_experts // model_size
+    assert cfg.held_experts % model_size == 0, (cfg.held_experts, model_size)
 
     # FSDP all-gather of this shard's expert weights over the data axis.
     if data_size > 1:
@@ -77,16 +131,12 @@ def _local_moe(router_w, w_gate, w_up, w_down, x, *, cfg: ModelConfig,
             w_down = jax.lax.all_gather(w_down, axes.data, axis=1, tiled=True)
 
     with jax.named_scope("router"):
-        logits = (x_flat.astype(jnp.float32) @ router_w)  # (T, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_vals, top_idx = jax.lax.top_k(probs, cfg.top_k)  # (T, k)
-        top_vals = top_vals / jnp.maximum(top_vals.sum(-1, keepdims=True),
-                                          1e-9)
+        top_vals, top_idx, scores = _route(x_flat, router_w, bias, cfg)
 
     cap = _capacity(cfg, t)
     act = activation(act_name)
     out = jnp.zeros((t, d), jnp.float32)
-    offset = jax.lax.axis_index(axes.model) * e_loc
+    offset = cfg.first_held_expert + jax.lax.axis_index(axes.model) * e_loc
     for e_local in range(e_loc):
         e_id = offset + e_local
         with jax.named_scope("dispatch"):
@@ -105,12 +155,10 @@ def _local_moe(router_w, w_gate, w_up, w_down, x, *, cfg: ModelConfig,
     with jax.named_scope("combine"):
         out = jax.lax.psum(out, axes.model)
 
-    # Router stats: tokens-per-expert counts + Switch-style aux loss.
+    # Router stats: tokens-per-expert counts (all experts, held or not) and
+    # the balance loss.
     with jax.named_scope("stats"):
-        assign = jax.nn.one_hot(top_idx[:, 0], e, dtype=jnp.float32)  # top-1
-        f_frac = assign.mean(0)
-        p_mean = probs.mean(0)
-        aux = e * jnp.sum(f_frac * p_mean)
+        aux = _aux_loss(top_idx, scores, cfg, b)
         counts = jnp.zeros((e,), jnp.float32)
         for k in range(cfg.top_k):
             counts = counts + jax.nn.one_hot(top_idx[:, k], e,
@@ -128,12 +176,26 @@ def moe_forward(params, x, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes,
         _local_moe, cfg=cfg, axes=axes, act_name=act_name,
         model_size=int(mesh.shape[axes.model]),
         data_size=int(mesh.shape[axes.data]))
+    bias = params.get("router_bias")
+    if bias is None:
+        bias = jnp.zeros((cfg.num_experts,), jnp.float32)
+
+    def body(*args):
+        # on a mesh of several devices the body's names gain a ``shard_map``
+        # segment after ``moe``: open ``moe`` again inside, so that its parts
+        # read ``moe/router``, ``moe/experts``, ... there too
+        if mesh.size == 1:
+            return fn(*args)
+        with jax.named_scope("moe"):
+            return fn(*args)
+
     with jax.named_scope("moe"):
         y, aux, counts = jax.shard_map(
-            fn,
+            body,
             mesh=mesh,
             in_specs=(
                 P(None, None),                       # router (d, E) replicated
+                P(None),                             # router_bias (E,)
                 P(axes.model, None, axes.data),      # w_gate (E, d, f)
                 P(axes.model, None, axes.data),      # w_up
                 P(axes.model, axes.data, None),      # w_down (E, f, d)
@@ -141,10 +203,19 @@ def moe_forward(params, x, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes,
             ),
             out_specs=(P(bspec, None, None), P(), P()),
             check_vma=False,
-        )(params["router"], params["w_gate"], params["w_up"],
-          params["w_down"], x)
+        )(params["router"], jax.lax.stop_gradient(bias), params["w_gate"],
+          params["w_up"], params["w_down"], x)
 
         if cfg.num_shared_experts:
             from repro.models.layers import mlp_forward
-            y = y + mlp_forward(params["shared"], x, act_name)
+            with jax.named_scope("shared"):
+                y = y + mlp_forward(params["shared"], x, act_name)
     return y, {"aux_loss": aux, "expert_counts": counts}
+
+
+def update_router_bias(bias, counts, rate: float):
+    """DeepSeek-V3's aux-loss-free balancing: each expert's bias moves by
+    ``rate`` towards the mean load, ``b_i + rate * sign(mean - load_i)``,
+    from the step's routed counts (..., E)."""
+    load = counts.astype(jnp.float32)
+    return bias + rate * jnp.sign(load.mean(-1, keepdims=True) - load)

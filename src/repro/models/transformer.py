@@ -2,9 +2,10 @@
 
 Layer stacks are ``jax.lax.scan``s over stacked period params (period = the
 repeating ``block_pattern``; gemma2 = (local, full), recurrentgemma =
-(rglru, rglru, local)); layers beyond the last full period are unrolled
-("tail").  This keeps HLO size O(1) in depth, which matters for both compile
-time and the dry-run.
+(rglru, rglru, local)); leading dense layers (``first_dense_layers``,
+"lead") run before the scan and layers beyond the last full period after it
+("tail"), both unrolled.  This keeps HLO size O(1) in depth, which matters
+for both compile time and the dry-run.
 
 Three paths per architecture: ``lm_loss`` (training), ``lm_prefill`` and
 ``lm_decode`` (serving with per-family state: KV cache for attention blocks,
@@ -83,11 +84,17 @@ def stack_periods(trees):
 
 
 def split_layers(cfg: ModelConfig, num_layers: Optional[int] = None):
+    """(periods scanned, kinds of the tail) of the layers after the lead."""
     n = num_layers if num_layers is not None else cfg.num_layers
+    lead = min(cfg.first_dense_layers, n)
     period = cfg.pattern_period
-    n_periods = n // period
-    tail_kinds = cfg.layer_kinds(n)[n_periods * period:]
+    n_periods = (n - lead) // period
+    tail_kinds = cfg.layer_kinds(n)[lead + n_periods * period:]
     return n_periods, tuple(tail_kinds)
+
+
+def lead_kinds(cfg: ModelConfig) -> Tuple[str, ...]:
+    return cfg.layer_kinds()[:min(cfg.first_dense_layers, cfg.num_layers)]
 
 
 def init_lm(key, cfg: ModelConfig, dtype=jnp.bfloat16):
@@ -103,6 +110,11 @@ def init_lm(key, cfg: ModelConfig, dtype=jnp.bfloat16):
             init_period(k, cfg.block_pattern, cfg, dtype) for k in period_keys]),
         "final_norm": zeros_init((cfg.d_model,), ("embed",), dtype=jnp.float32),
     }
+    if lead_kinds(cfg):
+        lkeys = jax.random.split(keys[4], len(lead_kinds(cfg)))
+        params["lead"] = {f"l{i}": init_block(k, kind, cfg, dtype)
+                          for i, (k, kind) in enumerate(zip(lkeys,
+                                                            lead_kinds(cfg)))}
     if tail_kinds:
         tkeys = jax.random.split(keys[2], len(tail_kinds))
         params["tail"] = {f"t{i}": init_block(k, kind, cfg, dtype)
@@ -230,7 +242,12 @@ def _unrolled_scan(body, carry, xs, n_steps: int):
 
 def run_stack(params, x, positions, ctx: Ctx, kinds, n_periods, tail_kinds,
               collect_cache: bool = False):
-    """Scan over periods + unrolled tail.  Returns (x, stats, caches)."""
+    """Unrolled lead + scan over periods + unrolled tail.  Returns (x,
+    stats, caches)."""
+    for i, kind in enumerate(lead_kinds(ctx.cfg)):
+        x = _remat(lambda x, p, kind=kind: block_train(
+            p, kind, x, positions, ctx)[0], ctx.cfg)(x, params["lead"][f"l{i}"])
+
     def period_fn(x, p_period):
         stats, caches = [], {}
         for i, kind in enumerate(kinds):
@@ -355,16 +372,43 @@ def lm_loss(params, batch, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes):
     loss, denom = masked_cross_entropy(params, x, targets, cfg, ctx)
     metrics = {"ce_loss": loss, "tokens": denom}
     if "aux_loss" in stats:
-        aux = 0.01 * stats["aux_loss"]
+        aux = cfg.aux_loss_weight * stats["aux_loss"]
         metrics["moe_aux_loss"] = stats["aux_loss"]
         metrics["expert_counts"] = stats["expert_counts"]
         loss = loss + aux
     return loss, metrics
 
 
+# -------------------------------------------------------------- router state
+def update_router_bias(params, expert_counts, cfg: ModelConfig):
+    """Move every MoE layer's ``router_bias`` against the load the step
+    routed to each expert (``moe.update_router_bias``).  ``expert_counts``
+    is the loss's (periods, E): one row per scanned layer, so the pattern
+    must be one MoE block with no MoE layer outside the scan."""
+    if (cfg.block_pattern != (BLOCK_MOE,)
+            or BLOCK_MOE in split_layers(cfg)[1] + lead_kinds(cfg)):
+        raise NotImplementedError("the router bias is updated for a "
+                                  "pattern of one MoE block only")
+    with jax.named_scope("router_bias"):
+        blk = params["scan"]["b0"]
+        moe = dict(blk["moe"])
+        moe["router_bias"] = moe_lib.update_router_bias(
+            moe["router_bias"], expert_counts, cfg.router_bias_rate)
+        scan = dict(params["scan"], b0=dict(blk, moe=moe))
+        return dict(params, scan=scan)
+
+
 # ------------------------------------------------------------------- serving
+def _refuse_serving(cfg: ModelConfig):
+    if cfg.attn_type == "mla" or cfg.first_dense_layers:
+        raise NotImplementedError(
+            f"{cfg.name}: serving (prefill and decode) has no latent-attention "
+            "cache and no leading dense layers; only training runs it")
+
+
 def lm_prefill(params, batch, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes):
     """Prompt pass: returns (cache, last-position logits)."""
+    _refuse_serving(cfg)
     ctx = Ctx(cfg, mesh, axes)
     x, positions = lm_inputs(params, batch, cfg)
     x = ctx.bconstrain(x)
@@ -379,6 +423,7 @@ def lm_prefill(params, batch, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes):
 def lm_decode(params, caches, token, pos, cfg: ModelConfig, mesh: Mesh,
               axes: MeshAxes):
     """One-token decode.  token: (B,1) int32; pos: int32 scalar."""
+    _refuse_serving(cfg)
     ctx = Ctx(cfg, mesh, axes)
     x = embed_tokens(params, token, cfg)
     n_periods, tail_kinds = split_layers(cfg)

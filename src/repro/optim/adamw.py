@@ -31,8 +31,12 @@ def global_norm(tree) -> jnp.ndarray:
 
 
 def adamw_update(grads, state: AdamWState, params, lr, *, b1=0.9, b2=0.95,
-                 eps=1e-8, weight_decay=0.0, clip_norm=1.0):
-    """Returns (new_params, new_state).  ``lr`` may be a scalar or schedule(step)."""
+                 eps=1e-8, weight_decay=0.0, clip_norm=1.0, skip=None):
+    """Returns (new_params, new_state).  ``lr`` may be a scalar or schedule(step).
+
+    ``skip(path)``, given a leaf's key path, marks state that is no
+    parameter of the optimizer: it is neither moved nor decayed, and its
+    moments stay zero."""
     with jax.named_scope("optimizer"):
         step = state.step + 1
         if callable(lr):
@@ -53,11 +57,13 @@ def adamw_update(grads, state: AdamWState, params, lr, *, b1=0.9, b2=0.95,
             p2 = p.astype(jnp.float32) - lr * delta
             return p2.astype(p.dtype), m2, v2
 
-        flat_p, treedef = jax.tree.flatten(params)
+        paths, treedef = jax.tree_util.tree_flatten_with_path(params)
+        flat_p = [p for _, p in paths]
         flat_g = treedef.flatten_up_to(grads)
         flat_m = treedef.flatten_up_to(state.m)
         flat_v = treedef.flatten_up_to(state.v)
-        out = [upd(g, m, v, p) for g, m, v, p in zip(flat_g, flat_m, flat_v, flat_p)]
+        out = [(p, m, v) if skip and skip(path) else upd(g, m, v, p)
+               for (path, p), g, m, v in zip(paths, flat_g, flat_m, flat_v)]
         new_p = treedef.unflatten([o[0] for o in out])
         new_m = treedef.unflatten([o[1] for o in out])
         new_v = treedef.unflatten([o[2] for o in out])

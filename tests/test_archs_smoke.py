@@ -74,6 +74,7 @@ def test_full_config_matches_assignment(arch):
         "llama4-scout-17b-a16e": (48, 5120, 40, 8, 8192, 202048),
         "rwkv6-7b": (32, 4096, None, None, 14336, 65536),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
     }[arch]
     layers, d, h, kv, ff, vocab = expected
     assert cfg.num_layers == layers and cfg.d_model == d
@@ -86,11 +87,19 @@ def test_full_config_matches_assignment(arch):
         assert cfg.num_experts == 128 and cfg.top_k == 8 and cfg.moe_d_ff == 768
     if arch == "llama4-scout-17b-a16e":
         assert cfg.num_experts == 16 and cfg.top_k == 1
+    if arch == "moonlight-16b-a3b":
+        assert (cfg.num_experts, cfg.top_k, cfg.moe_d_ff) == (64, 6, 1408)
+        assert (cfg.attn_type, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                cfg.qk_rope_head_dim, cfg.v_head_dim) == ("mla", 512, 128,
+                                                          64, 128)
+        assert cfg.first_dense_layers == 1 and cfg.shared_width == 2816
+        assert (cfg.router_scoring, cfg.routed_scaling) == ("sigmoid", 2.446)
 
 
 def test_shape_cells_cover_assignment():
     cells = list(configs.cells())
-    # 10 archs x 4 shapes - 7 long_500k skips (DESIGN.md) = 33
-    assert len(cells) == 33
+    # 10 archs x 4 shapes - 7 long_500k skips (DESIGN.md) = 33, and
+    # moonlight's train_4k (serving has no latent-attention cache)
+    assert len(cells) == 34
     long_runners = {a for a, s in cells if s == "long_500k"}
     assert long_runners == {"gemma2-27b", "rwkv6-7b", "recurrentgemma-9b"}

@@ -120,3 +120,59 @@ def test_the_fused_kernel_carries_attn(kernel_names, phase):
     assert ops, phase
     scope = "rematted_computation/attn" if phase == "recomputed" else "attn"
     assert all(_carries(n, scope) for n in ops), phase
+
+
+MLA_SCOPES = ("attn/core", "attn/latent", "moe/shared", "router_bias")
+
+
+@pytest.fixture(scope="module")
+def mla_names():
+    return _op_names("moonlight-16b-a3b")
+
+
+@pytest.mark.parametrize("scope", MLA_SCOPES)
+def test_latent_attention_and_shared_experts_carry_their_scopes(mla_names,
+                                                                scope):
+    """Moonlight's step names the attention core, the compressed-KV path,
+    the shared experts and the router bias's update; the first three in
+    the backward pass too."""
+    assert any(_carries(n, scope) for n in mla_names), scope
+    if scope != "router_bias":
+        assert any(_carries(n, scope) for n in mla_names
+                   if "transpose(" in n), scope
+
+
+SHARDED = r"""
+import re, jax, jax.numpy as jnp
+from repro import configs
+from repro.launch.mesh import make_local_mesh
+from repro.launch.steps import abstract_opt, abstract_params, make_train_step
+from repro.models.model import build_model
+model = build_model(configs.get_smoke_config("qwen3-moe-30b-a3b"),
+                    make_local_mesh(1, 2))
+params, p_sh = abstract_params(model)
+opt, _ = abstract_opt(params, p_sh)
+batch = {k: jax.ShapeDtypeStruct((2, 32), jnp.int32)
+         for k in ("tokens", "targets")}
+text = jax.jit(make_train_step(model)).lower(params, opt, batch).compile().as_text()
+print("\n".join(sorted(set(re.findall(r'op_name="([^"]*)"', text)))))
+"""
+
+
+def test_the_expert_layer_keeps_its_scopes_on_a_sharded_mesh():
+    """With the experts over two devices the body's names gain a
+    ``shard_map`` segment; each part still reads ``moe/<part>``."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    p = subprocess.run([sys.executable, "-c", SHARDED], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    names = p.stdout.splitlines()
+    assert any("shard_map" in n for n in names)
+    for scope in MOE_SCOPES:
+        assert any(_carries(n, scope) for n in names), scope

@@ -153,3 +153,41 @@ def test_attention_kernel_is_sharded_on_the_2x2_mesh(topo, no_compile_cache):
     # each chip holds a quarter of the work: 2 of the 4 sequences, 16 of
     # the 32 heads
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_moonlight_cut_step_fits_one_v5e_at_batch_4(topo, no_compile_cache):
+    """The train step of the Moonlight-16B-A3B cell (five layers: the dense
+    one and four MoE layers of 8 held experts of 64; a 20480-id vocabulary
+    slice) at seq 8192 and batch 4 compiles for one v5e and fits it: about
+    5.7 GB of bf16 weights and f32 moments and 6.8 GB of temporaries.
+    Latent attention's core runs on the fused kernel at qk 192 / v 128 in
+    the dense layer and the scanned MoE layer (forward, recompute,
+    backward)."""
+    import dataclasses
+
+    from repro.launch.steps import abstract_opt, abstract_params, \
+        make_train_step
+    from repro.models.model import build_model
+    cfg = dataclasses.replace(configs.get_config("moonlight-16b-a3b"),
+                              num_layers=5, vocab_size=20480, experts_held=8)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    model = build_model(cfg, mesh)
+    p_sds, p_sh = abstract_params(model)
+    o_sds, o_sh = abstract_opt(p_sds, p_sh)
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), tree, shardings)
+
+    one = NamedSharding(mesh, P())
+    batch = {k: jax.ShapeDtypeStruct((4, 8192), jnp.int32, sharding=one)
+             for k in ("tokens", "targets")}
+    compiled = jax.jit(make_train_step(model), out_shardings=(p_sh, o_sh, None),
+                       donate_argnums=(0, 1)).lower(
+        placed(p_sds, p_sh), placed(o_sds, o_sh), batch).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 6
+    mem = compiled.memory_analysis()
+    n = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(p_sds))
+    assert 5.6e8 < n < 5.8e8                       # 568.5M parameters held
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
