@@ -24,10 +24,12 @@ from repro import configs
 from repro.balance.expert_placement import plan_expert_placement
 from repro.checkpoint import CheckpointManager
 from repro.data.pipeline import make_batch
-from repro.launch.mesh import make_local_mesh, make_production_mesh
+from repro.launch.mesh import axes_for, make_local_mesh, \
+    make_production_mesh
 from repro.launch.steps import abstract_opt, abstract_params, make_train_step
 from repro.models.layers import split_lp_tree
 from repro.models.model import build_model
+from repro.models.moe import expert_dropped
 from repro.optim import adamw_init
 from repro.runtime.fault import FaultInjector, run_with_restarts
 
@@ -74,7 +76,8 @@ def train_loop(cfg, mesh, *, steps: int, seq_len: int, global_batch: int,
         dt = time.time() - t0
         losses.append(loss)
         if step % log_every == 0 or step == steps - 1:
-            print(f"[train] step {step} loss {loss:.4f} ({dt:.2f}s)",
+            print(f"[train] step {step} loss {loss:.4f} ({dt:.2f}s)"
+                  + _routing_note(metrics, cfg, mesh, seq_len, global_batch),
                   flush=True)
         if mgr and ((step + 1) % ckpt_every == 0 or step == steps - 1):
             mgr.save(step + 1, (params, opt_state))
@@ -87,6 +90,20 @@ def train_loop(cfg, mesh, *, steps: int, seq_len: int, global_batch: int,
     if mgr:
         mgr.wait()
     return params, opt_state, losses
+
+
+def _routing_note(metrics, cfg, mesh, seq_len: int, global_batch: int) -> str:
+    """The step's routed assignments and those its held experts dropped at
+    capacity (``moe.expert_dropped``), for the log; empty without an
+    expert layer."""
+    if "expert_counts" not in metrics:
+        return ""
+    counts = np.asarray(metrics["expert_counts"])
+    shards = int(np.prod([mesh.shape[a] for a in axes_for(mesh).batch]))
+    dropped = expert_dropped(counts, cfg, global_batch * seq_len // shards,
+                             shards)
+    return (f"; routed {int(counts.sum())}, expert_dropped {dropped}, "
+            f"max/mean count {float(counts.max() / counts.mean()):.3f}")
 
 
 def _permute_experts(params, opt_state, perms, cfg):

@@ -3,8 +3,10 @@
 Baseline collective schedule ("replicated-token EP"): activations are batch-
 sharded over the data axes and replicated over the model axis (standard TP
 layout between blocks), experts are sharded over the model axis, and each
-model-shard processes the tokens routed to *its* experts via per-expert
-top-capacity gather -> GEMM -> scatter; results combine with a single psum
+model-shard processes the tokens routed to *its* experts: one ``top_k``
+over the held experts' token weights fills each expert's capacity slots, one
+gather moves the tokens there, one batched GEMM runs every held expert, and
+the weighted rows return to their tokens; results combine with a single psum
 over the model axis.  Expert weights are FSDP-sharded over the data axis on
 the hidden dim and all-gathered at use.
 
@@ -31,6 +33,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -72,6 +75,18 @@ def _capacity(cfg: ModelConfig, tokens: int) -> int:
     return max(1, min(c, tokens))
 
 
+def expert_dropped(counts, cfg: ModelConfig, tokens: int, shards: int) -> int:
+    """Routed assignments the held experts drop at capacity: over layers
+    and held experts, ``max(count - shards * capacity, 0)``, from a step's
+    ``expert_counts`` (..., E), summed over ``shards`` data shards of
+    ``tokens`` tokens each.  Exact on one shard; a lower bound on more,
+    where one shard may drop what another has room for."""
+    held = np.asarray(counts, np.float64)[
+        ..., cfg.first_held_expert:cfg.first_held_expert + cfg.held_experts]
+    cap = shards * _capacity(cfg, tokens)
+    return int(np.maximum(held - cap, 0).sum())
+
+
 def _route(x_flat, router_w, bias, cfg: ModelConfig):
     """(top_vals, top_idx, scores): the chosen experts' weights and ids
     (T, k), and the per-token scores the balance loss reads (T, E)."""
@@ -108,6 +123,109 @@ def _aux_loss(top_idx, scores, cfg: ModelConfig, batch: int):
     return e * jnp.sum(assign.mean(0) * scores.mean(0))
 
 
+def _dispatch_plan(top_vals, top_idx, offset, e_loc: int, cap: int):
+    """Each held expert's capacity slots: its ``cap`` highest-weighted
+    tokens, ties to the lower token, from one ``top_k`` over the (E_loc, T)
+    weights of all held experts.
+
+    Returns ``(slot_token, slot_weight)`` (E_loc, C): each slot's token and
+    weight; where fewer tokens reach the expert, a token out of range (each
+    a different one) and weight 0.
+    """
+    t = top_idx.shape[0]
+    held = offset + jnp.arange(e_loc)
+    w = jnp.where(top_idx[None] == held[:, None, None], top_vals[None],
+                  0.0).sum(-1)  # (E_loc, T)
+    sel_w, sel_i = jax.lax.top_k(jnp.where(w > 0, w, -1.0), cap)
+    filled = sel_w > 0
+    empty = jnp.arange(e_loc * cap, dtype=jnp.int32).reshape(e_loc, cap)
+    return (jnp.where(filled, sel_i, t + empty),
+            jnp.where(filled, sel_w, 0.0))
+
+
+def _token_slots(top_idx, slot_token, offset, cap: int):
+    """(T, k): each token's slots in ascending order, which is ascending
+    expert order; a dropped or foreign assignment's slot is out of range
+    (each a different one) and sorts last."""
+    t, k = top_idx.shape
+    e_loc = slot_token.shape[0]
+    rank = jnp.full((t, e_loc), -1, jnp.int32).at[
+        slot_token, jnp.arange(e_loc)[:, None]].set(
+        jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32), slot_token.shape),
+        mode="drop", unique_indices=True)
+    loc = top_idx - offset
+    r = jnp.take_along_axis(rank, jnp.clip(loc, 0, e_loc - 1), axis=1)
+    kept = (loc >= 0) & (loc < e_loc) & (r >= 0)
+    out = e_loc * cap + jnp.arange(t * k, dtype=jnp.int32).reshape(t, k)
+    return jnp.sort(jnp.where(kept, loc * cap + r, out), axis=-1)
+
+
+def _token_sums(rows, slot_token, slot, t: int, descending: bool):
+    """(T, d): each token's rows of ``rows`` (E_loc, C, d), added from 0 in
+    ``rows``' dtype in its experts' order, ascending or descending.  With
+    the tokens' ``slot`` (T, k), by one gather per choice; without, by one
+    scatter-add of the slots, which are expert-major."""
+    if slot is None:
+        if descending:
+            rows, slot_token = rows[::-1], slot_token[::-1]
+        return jnp.zeros((t, rows.shape[-1]), rows.dtype).at[slot_token].add(
+            rows, mode="drop")
+    flat = rows.reshape(-1, rows.shape[-1])
+    out = jnp.zeros((t, rows.shape[-1]), rows.dtype)
+    for j in (reversed(range(slot.shape[1])) if descending
+              else range(slot.shape[1])):
+        out = out + flat.at[slot[:, j]].get(mode="fill", fill_value=0,
+                                            unique_indices=True)
+    return out
+
+
+def _take_tokens(x_flat, slot_token):
+    """(E_loc, C, d): each slot's token row; an empty slot reads the last
+    token, whose row its weight of 0 keeps out of every sum."""
+    return x_flat.at[slot_token].get(mode="clip")
+
+
+# Rows move between tokens (T, d) and capacity slots (E_loc, C, d) by a
+# gather one way and a sum over each token's slots the other; each move is
+# the other's transpose.  The combine adds a token's rows in ascending
+# expert order; the dispatch's transpose in descending order, the order in
+# which reverse mode accumulates the cotangents of one gather per expert,
+# so that the sums round as a per-expert formulation's do.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_slots(x_flat, slot_token, slot, t: int):
+    return _take_tokens(x_flat, slot_token)
+
+
+def _to_slots_fwd(x_flat, slot_token, slot, t):
+    return _take_tokens(x_flat, slot_token), (slot_token, slot)
+
+
+def _to_slots_bwd(t, res, g):
+    slot_token, slot = res
+    return _token_sums(g, slot_token, slot, t, descending=True), None, None
+
+
+_to_slots.defvjp(_to_slots_fwd, _to_slots_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_tokens(rows, slot_token, slot, t: int):
+    return _token_sums(rows, slot_token, slot, t, descending=False)
+
+
+def _to_tokens_fwd(rows, slot_token, slot, t):
+    return (_token_sums(rows, slot_token, slot, t, descending=False),
+            (slot_token, slot))
+
+
+def _to_tokens_bwd(t, res, g):
+    slot_token, _ = res
+    return _take_tokens(g, slot_token), None, None
+
+
+_to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
+
+
 def _local_moe(router_w, bias, w_gate, w_up, w_down, x, *, cfg: ModelConfig,
                axes: MeshAxes, act_name: str, model_size: int, data_size: int):
     """Per-device body under shard_map.
@@ -115,6 +233,10 @@ def _local_moe(router_w, bias, w_gate, w_up, w_down, x, *, cfg: ModelConfig,
     x: (B_loc, S, d) — identical across the model axis, sharded over batch.
     w_*: (E_loc, d, f_loc) — the held experts sharded over model, fsdp over
     data; this shard holds experts ``offset .. offset + E_loc - 1``.
+
+    Each held expert runs its ``cap`` highest-weighted tokens (ties to the
+    lower token), all experts in one batched GEMM; a token's weighted
+    outputs are added in f32 in ascending expert order.
     """
     b, s, d = x.shape
     t = b * s
@@ -135,24 +257,22 @@ def _local_moe(router_w, bias, w_gate, w_up, w_down, x, *, cfg: ModelConfig,
 
     cap = _capacity(cfg, t)
     act = activation(act_name)
-    out = jnp.zeros((t, d), jnp.float32)
     offset = cfg.first_held_expert + jax.lax.axis_index(axes.model) * e_loc
-    for e_local in range(e_loc):
-        e_id = offset + e_local
-        with jax.named_scope("dispatch"):
-            w_e = jnp.where(top_idx == e_id, top_vals, 0.0).sum(-1)  # (T,)
-            sel_w, sel_i = jax.lax.top_k(jnp.where(w_e > 0, w_e, -1.0), cap)
-            valid = (sel_w > 0).astype(jnp.float32)
-            xg = x_flat[sel_i]  # (C, d)
-        with jax.named_scope("experts"):
-            g = act(xg @ w_gate[e_local])
-            u = xg @ w_up[e_local]
-            h = ((g * u) @ w_down[e_local]).astype(jnp.float32)
-        with jax.named_scope("combine"):
-            h = h * (sel_w * valid)[:, None]
-            out = out.at[sel_i].add(h)
-
+    with jax.named_scope("dispatch"):
+        slot_token, slot_weight = _dispatch_plan(top_vals, top_idx, offset,
+                                                 e_loc, cap)
+        # a token's rows are gathered from its slots where there are no more
+        # assignments than slots; where most are foreign (an expert-parallel
+        # shard) or dropped, the slots are scattered to their tokens
+        slot = (_token_slots(top_idx, slot_token, offset, cap)
+                if t * cfg.top_k <= e_loc * cap else None)
+        xg = _to_slots(x_flat, slot_token, slot, t)  # (E_loc, C, d)
+    with jax.named_scope("experts"):
+        g = act(jnp.einsum("ecd,edf->ecf", xg, w_gate))
+        u = jnp.einsum("ecd,edf->ecf", xg, w_up)
+        h = jnp.einsum("ecf,efd->ecd", g * u, w_down).astype(jnp.float32)
     with jax.named_scope("combine"):
+        out = _to_tokens(h * slot_weight[..., None], slot_token, slot, t)
         out = jax.lax.psum(out, axes.model)
 
     # Router stats: tokens-per-expert counts (all experts, held or not) and
