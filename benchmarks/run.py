@@ -30,22 +30,19 @@ DISPLAY = {
     "delta_sweep": "fig4b_delta_sweep",
     "assembly_scaling": "fig5_assembly_scaling",
     "costmodel_eval": "costmodel",
-    "kernels_bench": "kernels",
 }
 ORDER = ["milp_vs_ccmlb", "delta_sweep", "assembly_scaling", "costmodel_eval",
          "ccmlb_scaling", "ccmlb_spec", "ccmlb_fleet", "ccmlb_pipeline",
          "ccmlb_async", "ccmlb_fault", "ccmlb_memory", "ccmlb_quiesce",
          "scorer_paths",
-         "kernels_bench",
-         "expert_placement",
-         "roofline"]
+         "expert_placement"]
 
 
 def discover():
     """``(modules, failed)``: (display_name, module) for every benchmarks
     submodule with run(), and the names of those that failed to import."""
     names = [m.name for m in pkgutil.iter_modules(benchmarks.__path__)
-             if m.name not in ("run", "render_experiments")]
+             if m.name != "run"]
     names.sort(key=lambda n: (ORDER.index(n) if n in ORDER else len(ORDER), n))
     out, failed = [], []
     for name in names:

@@ -31,7 +31,6 @@ CONFIG_100M = ModelConfig(
     top_k=2,
     moe_d_ff=512,
     act="silu",
-    remat=False,
 )
 
 

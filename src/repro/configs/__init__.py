@@ -65,7 +65,7 @@ def get_shape(name: str) -> ShapeConfig:
 
 
 def cells():
-    """All runnable (arch, shape) dry-run cells, skips applied."""
+    """All runnable (arch, shape) cells of the assignment, skips applied."""
     for arch in ARCH_IDS:
         cfg = get_config(arch)
         for shape in cfg.shapes():

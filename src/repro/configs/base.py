@@ -108,30 +108,12 @@ class ModelConfig:
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
 
-    # --- which assigned shape cells run (skips noted in DESIGN.md) ----------
+    # --- which assigned shape cells run ---------------------------------------
     skip_shapes: Tuple[str, ...] = ()
 
-    # --- distribution defaults (overridable by the launcher) -----------------
-    remat: bool = True
-    remat_policy: str = "full"   # full | dots (save matmul outputs) | none
-    # Unroll the layer stack instead of lax.scan.  XLA's HloCostAnalysis
-    # counts a while-loop body ONCE (verified: a scan of 10 matmuls reports
-    # 1/10th of the flops), so the dry-run lowers with unroll_stack=True to
-    # get exact per-cell flops/bytes/collective counts; production lowering
-    # keeps the scan for O(1) HLO size.
-    unroll_stack: bool = False
-
-    # --- beyond-paper perf knobs (EXPERIMENTS.md §Perf) -----------------------
-    ce_chunk: int = 0            # >0: cross-entropy in seq chunks (kills the
-                                 # (B,S,V) f32 logits residency)
-    attn_kv_chunk: int = 0       # >0: flash-style online-softmax attention
-                                 # over KV chunks in the XLA path (kills the
-                                 # (B,H,S,S) score residency)
+    # --- serving --------------------------------------------------------------
     window_kv_cache: bool = False  # local_attn decode: ring cache of window
                                    # size instead of full seq length
-    shard_rnn: bool = True       # shard recurrent width over 'model'; False
-                                 # replicates the rnn block (trades 16x gate
-                                 # compute for zero rnn-psum collectives)
 
     def __post_init__(self):
         if self.head_dim == 0:
@@ -175,7 +157,8 @@ class ModelConfig:
         return tuple(s for s in ALL_SHAPES if s.name not in self.skip_shapes)
 
     def param_count(self) -> int:
-        """Analytic parameter count (used for 6ND model-FLOPs in roofline)."""
+        """Analytic parameter count; the examples report a model's size with
+        it."""
         d, v = self.d_model, self.vocab_size
         hd = self.head_dim
         total = v * d  # embeddings

@@ -2,7 +2,7 @@
 vocab=32000 — anyres tiling. [hf:llava-hf/llava-v1.6-mistral-7b-hf; unverified]
 
 The assignment specifies the transformer BACKBONE only; the vision frontend is
-a STUB — ``input_specs()`` provides precomputed anyres patch embeddings that
+a STUB — the batch carries precomputed anyres patch embeddings that
 occupy the first ``num_media_positions`` sequence slots.  Full attention ->
 long_500k skipped.
 """

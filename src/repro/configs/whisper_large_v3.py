@@ -4,7 +4,7 @@
 [arXiv:2212.04356; unverified]
 
 ``seq_len`` is interpreted as the encoder frame count (the audio frontend is a
-stub: ``input_specs`` provides precomputed frame embeddings); the decoder runs
+stub: the batch carries precomputed frame embeddings); the decoder runs
 min(448, seq_len // 8) text positions.  Full attention -> long_500k skipped.
 """
 from repro.configs.base import BLOCK_ATTN, ModelConfig

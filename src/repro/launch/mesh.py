@@ -1,8 +1,8 @@
 """Production meshes.
 
 ``make_production_mesh`` is a FUNCTION (not a module constant) so importing
-this module never touches jax device state; the 512-device host-platform
-override lives only in launch/dryrun.py.
+this module never touches jax device state; it needs the 256 (or 512)
+devices of a pod slice.
 """
 from __future__ import annotations
 

@@ -1,26 +1,22 @@
-"""Step builders: abstract (ShapeDtypeStruct) params/optimizer/batch trees with
-matching NamedShardings, and the jitted train/prefill/decode steps used by the
-trainer, the server, and the multi-pod dry-run.
+"""Step builders: abstract (ShapeDtypeStruct) parameter and optimizer trees
+with matching NamedShardings, and the train step that the trainer and the
+benchmark's drivers jit.
 """
 from __future__ import annotations
 
-import functools
 import re
 from pathlib import Path
-from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models.layers import split_lp_tree
-from repro.models.model import (Model, batch_specs, build_model, cache_specs,
-                                decode_token_specs)
+from repro.models.model import Model
 from repro.models.moe import is_router_state
 from repro.models.transformer import update_router_bias
-from repro.optim import adamw_init, adamw_update, warmup_cosine
-from repro.sharding import MeshAxes, shardings_for_lp_tree
+from repro.optim import adamw_update, warmup_cosine
+from repro.sharding import shardings_for_lp_tree
 
 # The model names its layers (jax.named_scope) for profiles.  By default the
 # persistent compile cache leaves the op metadata out of its key, so a step
@@ -33,12 +29,6 @@ if jax.config.jax_hlo_source_file_canonicalization_regex is None:
     jax.config.update("jax_hlo_source_file_canonicalization_regex",
                       "^" + re.escape(str(Path(__file__).resolve().parents[3]))
                       + "/")
-
-
-def named(mesh: Mesh, spec_tree):
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s), spec_tree,
-        is_leaf=lambda x: isinstance(x, P))
 
 
 def abstract_params(model: Model):
@@ -84,60 +74,3 @@ def make_train_step(model: Model, *, lr=3e-4, weight_decay=0.1,
         return new_params, new_opt, metrics
 
     return train_step
-
-
-def make_prefill_step(model: Model):
-    def prefill_step(params, batch):
-        return model.prefill_fn(params, batch)
-    return prefill_step
-
-
-def make_decode_step(model: Model):
-    def decode_step(params, cache, token, pos):
-        return model.decode_fn(params, cache, token, pos)
-    return decode_step
-
-
-# ------------------------------------------------------------------ lowering
-def lower_train(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh):
-    model = build_model(cfg, mesh)
-    params_sds, p_sh = abstract_params(model)
-    opt_sds, o_sh = abstract_opt(params_sds, p_sh)
-    batch_sds, b_specs = batch_specs(cfg, shape, mesh, model.axes, "train")
-    b_sh = named(mesh, b_specs)
-    step = make_train_step(model)
-    jitted = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
-                     donate_argnums=(0, 1))
-    return jitted.lower(params_sds, opt_sds, batch_sds)
-
-
-def lower_prefill(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh):
-    model = build_model(cfg, mesh)
-    params_sds, p_sh = abstract_params(model)
-    batch_sds, b_specs = batch_specs(cfg, shape, mesh, model.axes, "prefill")
-    jitted = jax.jit(make_prefill_step(model),
-                     in_shardings=(p_sh, named(mesh, b_specs)))
-    return jitted.lower(params_sds, batch_sds)
-
-
-def lower_decode(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh):
-    model = build_model(cfg, mesh)
-    params_sds, p_sh = abstract_params(model)
-    cache_sds, c_specs = cache_specs(cfg, shape, mesh, model.axes)
-    tok_sds, tok_spec, pos_sds, pos_spec = decode_token_specs(
-        cfg, shape, mesh, model.axes)
-    jitted = jax.jit(
-        make_decode_step(model),
-        in_shardings=(p_sh, named(mesh, c_specs),
-                      NamedSharding(mesh, tok_spec),
-                      NamedSharding(mesh, pos_spec)),
-        donate_argnums=(1,))
-    return jitted.lower(params_sds, cache_sds, tok_sds, pos_sds)
-
-
-def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh):
-    if shape.kind == "train":
-        return lower_train(cfg, shape, mesh)
-    if shape.kind == "prefill":
-        return lower_prefill(cfg, shape, mesh)
-    return lower_decode(cfg, shape, mesh)

@@ -115,43 +115,6 @@ def _sdpa(q, k, v, bias, logit_cap: float):
     return out.reshape(b, sq, h, v.shape[-1])
 
 
-def _sdpa_chunked(q, k, v, bias, logit_cap: float, kv_chunk: int):
-    """Flash-style online-softmax over KV chunks in the XLA path (§Perf:
-    the (Sq, Sk) score tile never exceeds (Sq, kv_chunk)).  Python loop so
-    the dry-run cost accounting stays exact (see ModelConfig.unroll_stack).
-    """
-    b, sq, h, hd = q.shape
-    sk = k.shape[1]
-    hkv = k.shape[2]
-    g = h // hkv
-    qf = q.reshape(b, sq, hkv, g, hd)
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    m = jnp.full((b, hkv, g, sq, 1), -1e30, jnp.float32)
-    l = jnp.zeros((b, hkv, g, sq, 1), jnp.float32)
-    acc = jnp.zeros((b, hkv, g, sq, v.shape[-1]), jnp.float32)
-    n_chunks = (sk + kv_chunk - 1) // kv_chunk
-    for ci in range(n_chunks):
-        lo = ci * kv_chunk
-        hi = min(lo + kv_chunk, sk)
-        kc = k[:, lo:hi]
-        vc = v[:, lo:hi]
-        bias_c = bias[:, :, :, lo:hi]
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qf, kc).astype(jnp.float32)
-        s = softcap(s * scale, logit_cap) + bias_c[:, :, None, :, :]
-        m_cur = s.max(-1, keepdims=True)
-        m_new = jnp.maximum(m, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(s <= -1e29, 0.0, p)
-        corr = jnp.exp(m - m_new)
-        l = corr * l + p.sum(-1, keepdims=True)
-        acc = acc * corr + jnp.einsum("bhgqk,bkhd->bhgqd", p,
-                                      vc.astype(jnp.float32))
-        m = m_new
-    out = acc / jnp.where(l == 0.0, 1.0, l)
-    out = jnp.moveaxis(out, 3, 1)  # (b, sq, hkv, g, hd)
-    return out.reshape(b, sq, h, v.shape[-1]).astype(v.dtype)
-
-
 # ------------------------------------------------------------ fused kernel
 def use_fused_kernel(platform: str, sq: int, sk: int, mask_kind: str,
                      cross: bool) -> bool:
@@ -277,11 +240,7 @@ def attention_forward_kv(params, x, cfg: ModelConfig, *, mask_kind: str,
             if out is None:
                 bias = _mask_bias(positions, kv_pos, mask_kind,
                                   cfg.window_size)[:, None]
-                if cfg.attn_kv_chunk and k.shape[1] > cfg.attn_kv_chunk:
-                    out = _sdpa_chunked(q, k, v, bias, cfg.logit_softcap,
-                                        cfg.attn_kv_chunk)
-                else:
-                    out = _sdpa(q, k, v, bias, cfg.logit_softcap)
+                out = _sdpa(q, k, v, bias, cfg.logit_softcap)
         return jnp.einsum("bshe,hed->bsd", out, params["w_o"]), k, v
 
 

@@ -1,24 +1,20 @@
 """Uniform model API over all assigned architectures.
 
 ``build_model(cfg, mesh)`` returns a ``Model`` with init / loss / prefill /
-decode closures, plus ``input_specs`` and ``cache_specs`` used by the
-multi-pod dry-run (ShapeDtypeStruct stand-ins — no device allocation).
+decode closures.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Callable, Optional
 
-import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
-from repro.configs.base import (BLOCK_ATTN, BLOCK_LOCAL, BLOCK_MOE, BLOCK_REC,
-                                BLOCK_RWKV, ModelConfig, ShapeConfig)
+from repro.configs.base import ModelConfig
 from repro.models import encdec as encdec_lib
 from repro.models import transformer as tf_lib
-from repro.sharding import MeshAxes, batch_size_divisor, spec_for
+from repro.sharding import MeshAxes
 
 
 @dataclasses.dataclass
@@ -52,133 +48,3 @@ def build_model(cfg: ModelConfig, mesh: Mesh,
         decode_fn=lambda p, c, t, pos: tf_lib.lm_decode(
             p, c, t, pos, cfg, mesh, axes),
     )
-
-
-# -------------------------------------------------------------- input specs
-def _sds(shape, dtype):
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def _bspec(axes: MeshAxes, b: int, mesh: Mesh):
-    if b % batch_size_divisor(mesh, axes) == 0:
-        return axes.batch if len(axes.batch) > 1 else axes.batch[0]
-    return None
-
-
-def batch_specs(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
-                axes: MeshAxes, kind: str):
-    """(ShapeDtypeStruct tree, PartitionSpec tree) for a step's data batch.
-
-    kind: "train" | "prefill" — decode inputs are built separately.
-    """
-    b, s = shape.global_batch, shape.seq_len
-    bs = _bspec(axes, b, mesh)
-    dt = jnp.bfloat16
-    if cfg.arch_type == "encdec":
-        s_dec = encdec_lib.decoder_len(cfg, s)
-        batch = {"audio_embed": _sds((b, s, cfg.d_model), dt),
-                 "tokens": _sds((b, s_dec), jnp.int32)}
-        specs = {"audio_embed": P(bs, None, None), "tokens": P(bs, None)}
-        if kind == "train":
-            batch["targets"] = _sds((b, s_dec), jnp.int32)
-            specs["targets"] = P(bs, None)
-        return batch, specs
-    if cfg.frontend == "vision":
-        p_media = cfg.num_media_positions
-        s_text = s - p_media
-        batch = {"media_embed": _sds((b, p_media, cfg.d_model), dt),
-                 "tokens": _sds((b, s_text), jnp.int32)}
-        specs = {"media_embed": P(bs, None, None), "tokens": P(bs, None)}
-        if kind == "train":
-            batch["targets"] = _sds((b, s_text), jnp.int32)
-            specs["targets"] = P(bs, None)
-        return batch, specs
-    batch = {"tokens": _sds((b, s), jnp.int32)}
-    specs = {"tokens": P(bs, None)}
-    if kind == "train":
-        batch["targets"] = _sds((b, s), jnp.int32)
-        specs["targets"] = P(bs, None)
-    return batch, specs
-
-
-def _seq_shard(axes: MeshAxes, b: int, s: int, mesh: Mesh):
-    """(batch_entry, seq_entry) for KV caches: batch over the batch axes when
-    divisible, else shard the sequence dim as hard as divisibility allows."""
-    if b % batch_size_divisor(mesh, axes) == 0:
-        bspec = axes.batch if len(axes.batch) > 1 else axes.batch[0]
-        seq = axes.model if s % mesh.shape[axes.model] == 0 else None
-        return bspec, seq
-    combo = (axes.data, axes.model)
-    size = mesh.shape[axes.data] * mesh.shape[axes.model]
-    if s % size == 0:
-        return None, combo
-    return None, (axes.data if s % mesh.shape[axes.data] == 0 else None)
-
-
-def cache_specs(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
-                axes: MeshAxes):
-    """(ShapeDtypeStruct cache tree, PartitionSpec tree) for decode cells."""
-    b, s = shape.global_batch, shape.seq_len
-    hkv, hd, d = cfg.num_kv_heads, cfg.head_dim, cfg.d_model
-    cb, cs = _seq_shard(axes, b, s, mesh)
-
-    if cfg.arch_type == "encdec":
-        ldec = cfg.num_decoder_layers
-        s_dec = 448
-        cache = {"sk": _sds((ldec, b, s_dec, hkv, hd), jnp.bfloat16),
-                 "sv": _sds((ldec, b, s_dec, hkv, hd), jnp.bfloat16),
-                 "ck": _sds((ldec, b, s, hkv, hd), jnp.bfloat16),
-                 "cv": _sds((ldec, b, s, hkv, hd), jnp.bfloat16)}
-        sspec = P(None, cb, None, None, None)
-        cspec = P(None, cb, cs, None, None)
-        specs = {"sk": sspec, "sv": sspec, "ck": cspec, "cv": cspec}
-        return cache, specs
-
-    n_periods, tail_kinds = tf_lib.split_layers(cfg)
-    h_rwkv = d // cfg.rwkv_head_dim
-    rhd = cfg.rwkv_head_dim
-    model_ok = lambda dim: axes.model if dim % mesh.shape[axes.model] == 0 else None
-
-    def entry(kind: str, lead: Tuple[int, ...], lead_spec):
-        if kind in (BLOCK_ATTN, BLOCK_LOCAL, BLOCK_MOE):
-            s_eff = s
-            cb_e, cs_e = cb, cs
-            if kind == BLOCK_LOCAL and cfg.window_kv_cache:
-                s_eff = min(cfg.window_size, s)       # ring cache (§Perf)
-                cb_e, cs_e = _seq_shard(axes, b, s_eff, mesh)
-            sds = _sds(lead + (b, s_eff, hkv, hd), jnp.bfloat16)
-            spec = P(*lead_spec, cb_e, cs_e, None, None)
-            return {"k": sds, "v": sds}, {"k": spec, "v": spec}
-        if kind == BLOCK_RWKV:
-            return (
-                {"wkv": _sds(lead + (b, h_rwkv, rhd, rhd), jnp.float32),
-                 "tm_shift": _sds(lead + (b, d), jnp.bfloat16),
-                 "cm_shift": _sds(lead + (b, d), jnp.bfloat16)},
-                {"wkv": P(*lead_spec, cb, model_ok(h_rwkv), None, None),
-                 "tm_shift": P(*lead_spec, cb, model_ok(d)),
-                 "cm_shift": P(*lead_spec, cb, model_ok(d))})
-        if kind == BLOCK_REC:
-            w = cfg.rglru_conv_width
-            return (
-                {"h": _sds(lead + (b, d), jnp.float32),
-                 "conv": _sds(lead + (b, w - 1, d), jnp.bfloat16)},
-                {"h": P(*lead_spec, cb, model_ok(d)),
-                 "conv": P(*lead_spec, cb, None, model_ok(d))})
-        raise ValueError(kind)
-
-    scan_c, scan_s = {}, {}
-    for i, kind in enumerate(cfg.block_pattern):
-        scan_c[f"b{i}"], scan_s[f"b{i}"] = entry(kind, (n_periods,), (None,))
-    tail_c, tail_s = {}, {}
-    for i, kind in enumerate(tail_kinds):
-        tail_c[f"t{i}"], tail_s[f"t{i}"] = entry(kind, (), ())
-    return ({"scan": scan_c, "tail": tail_c},
-            {"scan": scan_s, "tail": tail_s})
-
-
-def decode_token_specs(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
-                       axes: MeshAxes):
-    b = shape.global_batch
-    bs = _bspec(axes, b, mesh)
-    return (_sds((b, 1), jnp.int32), P(bs, None),
-            _sds((), jnp.int32), P())

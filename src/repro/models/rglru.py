@@ -18,7 +18,6 @@ from repro.models.layers import LP, dense_init, zeros_init
 def init_rglru_block(key, cfg: ModelConfig, dtype=jnp.bfloat16):
     d = cfg.d_model
     dr = d  # rnn width = d_model
-    rnn = "rnn" if cfg.shard_rnn else None  # §Perf: collective/compute trade
     ks = jax.random.split(key, 6)
     lam = jax.random.uniform(ks[5], (dr,), jnp.float32, 0.9 ** 2, 0.999 ** 2)
     # parameterize a = sigmoid(lambda_p); init so sigmoid(lambda_p)=lam^(1/c) —
@@ -26,17 +25,17 @@ def init_rglru_block(key, cfg: ModelConfig, dtype=jnp.bfloat16):
     lambda_p = jnp.log(lam ** (1.0 / cfg.rglru_c) /
                        (1.0 - lam ** (1.0 / cfg.rglru_c)))
     return {
-        "w_in_x": dense_init(ks[0], (d, dr), ("embed", rnn), dtype=dtype),
-        "w_in_gate": dense_init(ks[1], (d, dr), ("embed", rnn), dtype=dtype),
-        "conv_w": zeros_init((cfg.rglru_conv_width, dr), ("conv", rnn),
+        "w_in_x": dense_init(ks[0], (d, dr), ("embed", "rnn"), dtype=dtype),
+        "w_in_gate": dense_init(ks[1], (d, dr), ("embed", "rnn"), dtype=dtype),
+        "conv_w": zeros_init((cfg.rglru_conv_width, dr), ("conv", "rnn"),
                              dtype=jnp.float32),
-        "conv_b": zeros_init((dr,), (rnn,), dtype=jnp.float32),
-        "w_a": dense_init(ks[2], (dr, dr), (rnn, rnn), dtype=dtype),
-        "b_a": zeros_init((dr,), (rnn,), dtype=jnp.float32),
-        "w_x": dense_init(ks[3], (dr, dr), (rnn, rnn), dtype=dtype),
-        "b_x": zeros_init((dr,), (rnn,), dtype=jnp.float32),
-        "lambda_p": LP(lambda_p, (rnn,)),
-        "w_out": dense_init(ks[4], (dr, d), (rnn, "embed"), dtype=dtype),
+        "conv_b": zeros_init((dr,), ("rnn",), dtype=jnp.float32),
+        "w_a": dense_init(ks[2], (dr, dr), ("rnn", "rnn"), dtype=dtype),
+        "b_a": zeros_init((dr,), ("rnn",), dtype=jnp.float32),
+        "w_x": dense_init(ks[3], (dr, dr), ("rnn", "rnn"), dtype=dtype),
+        "b_x": zeros_init((dr,), ("rnn",), dtype=jnp.float32),
+        "lambda_p": LP(lambda_p, ("rnn",)),
+        "w_out": dense_init(ks[4], (dr, d), ("rnn", "embed"), dtype=dtype),
     }
 
 

@@ -28,7 +28,6 @@ def init_time_mix(key, cfg: ModelConfig, dtype=jnp.bfloat16):
     d = cfg.d_model
     h = d // cfg.rwkv_head_dim
     hd = cfg.rwkv_head_dim
-    rnn = "rnn" if cfg.shard_rnn else None  # §Perf: collective/compute trade
     ks = jax.random.split(key, 12)
     return {
         "mu_x": zeros_init((d,), ("embed",), dtype=jnp.float32),
@@ -37,18 +36,18 @@ def init_time_mix(key, cfg: ModelConfig, dtype=jnp.bfloat16):
                             scale=0.1, dtype=jnp.float32),
         "mix_b": zeros_init((5, MIX_LORA, d), (None, "lora", "embed"),
                             dtype=jnp.float32),
-        "w0": LP(jnp.full((h, hd), -6.0, jnp.float32), (rnn, "head_dim")),
+        "w0": LP(jnp.full((h, hd), -6.0, jnp.float32), ("rnn", "head_dim")),
         "w_a": dense_init(ks[1], (d, DECAY_LORA), ("embed", "lora"),
                           scale=0.1, dtype=jnp.float32),
         "w_b": zeros_init((DECAY_LORA, d), ("lora", "embed"), dtype=jnp.float32),
-        "u": zeros_init((h, hd), (rnn, "head_dim"), dtype=jnp.float32),
-        "w_r": dense_init(ks[2], (d, d), ("embed", rnn), dtype=dtype),
-        "w_k": dense_init(ks[3], (d, d), ("embed", rnn), dtype=dtype),
-        "w_v": dense_init(ks[4], (d, d), ("embed", rnn), dtype=dtype),
-        "w_g": dense_init(ks[5], (d, d), ("embed", rnn), dtype=dtype),
-        "w_o": dense_init(ks[6], (d, d), (rnn, "embed"), dtype=dtype),
-        "ln_w": LP(jnp.ones((d,), jnp.float32), (rnn,)),
-        "ln_b": zeros_init((d,), (rnn,), dtype=jnp.float32),
+        "u": zeros_init((h, hd), ("rnn", "head_dim"), dtype=jnp.float32),
+        "w_r": dense_init(ks[2], (d, d), ("embed", "rnn"), dtype=dtype),
+        "w_k": dense_init(ks[3], (d, d), ("embed", "rnn"), dtype=dtype),
+        "w_v": dense_init(ks[4], (d, d), ("embed", "rnn"), dtype=dtype),
+        "w_g": dense_init(ks[5], (d, d), ("embed", "rnn"), dtype=dtype),
+        "w_o": dense_init(ks[6], (d, d), ("rnn", "embed"), dtype=dtype),
+        "ln_w": LP(jnp.ones((d,), jnp.float32), ("rnn",)),
+        "ln_b": zeros_init((d,), ("rnn",), dtype=jnp.float32),
     }
 
 

@@ -4,8 +4,9 @@ Layer stacks are ``jax.lax.scan``s over stacked period params (period = the
 repeating ``block_pattern``; gemma2 = (local, full), recurrentgemma =
 (rglru, rglru, local)); leading dense layers (``first_dense_layers``,
 "lead") run before the scan and layers beyond the last full period after it
-("tail"), both unrolled.  This keeps HLO size O(1) in depth, which matters
-for both compile time and the dry-run.
+("tail"), both unrolled.  This keeps HLO size, and with it compile time,
+O(1) in depth.  Every period runs under ``jax.checkpoint``: the backward
+pass recomputes its activations.
 
 Three paths per architecture: ``lm_loss`` (training), ``lm_prefill`` and
 ``lm_decode`` (serving with per-family state: KV cache for attention blocks,
@@ -219,34 +220,13 @@ def _merge_stats(stats_list):
     return out
 
 
-def _remat(fn, cfg: ModelConfig):
-    if not cfg.remat or cfg.remat_policy == "none":
-        return fn
-    if cfg.remat_policy == "dots":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    return jax.checkpoint(fn)
-
-
-def _unrolled_scan(body, carry, xs, n_steps: int):
-    """lax.scan semantics with a python loop (dry-run flop-count accuracy:
-    XLA's cost analysis visits a while body once, see ModelConfig)."""
-    ys = []
-    for i in range(n_steps):
-        x_i = jax.tree.map(lambda a: a[i], xs)
-        carry, y = body(carry, x_i)
-        ys.append(y)
-    stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *ys) if ys else {}
-    return carry, stacked
-
-
-def run_stack(params, x, positions, ctx: Ctx, kinds, n_periods, tail_kinds,
+def run_stack(params, x, positions, ctx: Ctx, kinds, tail_kinds,
               collect_cache: bool = False):
     """Unrolled lead + scan over periods + unrolled tail.  Returns (x,
     stats, caches)."""
     for i, kind in enumerate(lead_kinds(ctx.cfg)):
-        x = _remat(lambda x, p, kind=kind: block_train(
-            p, kind, x, positions, ctx)[0], ctx.cfg)(x, params["lead"][f"l{i}"])
+        x = jax.checkpoint(lambda x, p, kind=kind: block_train(
+            p, kind, x, positions, ctx)[0])(x, params["lead"][f"l{i}"])
 
     def period_fn(x, p_period):
         stats, caches = [], {}
@@ -258,12 +238,8 @@ def run_stack(params, x, positions, ctx: Ctx, kinds, n_periods, tail_kinds,
                 caches[f"b{i}"] = _pack_cache(kind, kv)
         return x, (_merge_stats(stats), caches)
 
-    body = _remat(period_fn, ctx.cfg)
-    if ctx.cfg.unroll_stack:
-        x, (stats, caches) = _unrolled_scan(body, x, params["scan"], n_periods)
-    else:
-        x, (stats, caches) = jax.lax.scan(
-            lambda c, p: body(c, p), x, params["scan"])
+    x, (stats, caches) = jax.lax.scan(jax.checkpoint(period_fn), x,
+                                      params["scan"])
     # scan stacks stats over periods: total the aux loss, keep per-layer counts.
     if "aux_loss" in stats:
         stats = {"aux_loss": stats["aux_loss"].sum(),
@@ -315,44 +291,23 @@ def lm_inputs(params, batch, cfg: ModelConfig):
 
 
 # -------------------------------------------------------------------- losses
-def _ce_piece(x, targets, table, cfg: ModelConfig, ctx: Ctx):
-    """(nll_sum, token_count) over one sequence piece."""
-    logits = jnp.einsum("bsd,dv->bsv", x, table).astype(jnp.float32)
-    logits = softcap(logits, cfg.final_softcap)
-    logits = constrain(logits, ctx.mesh, P(ctx.bspec, None, "model"))
-    lse = jax.nn.logsumexp(logits, axis=-1)  # (B,S)
-    mask = (targets >= 0)
-    safe = jnp.maximum(targets, 0)
-    lbl_w = jnp.take(table, safe, axis=1)            # (d, B, S)
-    lbl_logit = jnp.einsum("bsd,dbs->bs", x, lbl_w).astype(jnp.float32)
-    lbl_logit = softcap(lbl_logit, cfg.final_softcap)
-    nll = (lse - lbl_logit) * mask
-    return nll.sum(), mask.sum()
-
-
 def masked_cross_entropy(params, x, targets, cfg: ModelConfig, ctx: Ctx):
     """CE over the vocab without materializing a one-hot: logsumexp - label
-    logit (label logits via an lm_head gather, SPMD-friendly).
-
-    With cfg.ce_chunk > 0 the sequence is processed in chunks so the f32
-    (B, chunk, V) logits tile replaces the full (B, S, V) residency (§Perf
-    memory-term optimization)."""
+    logit (label logits via an lm_head gather, SPMD-friendly), averaged
+    over the targets >= 0.  Returns (loss, token count)."""
     with jax.named_scope("head"):
         table = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        s = x.shape[1]
-        if cfg.ce_chunk and s > cfg.ce_chunk:
-            nll_total = jnp.float32(0.0)
-            count = jnp.int32(0)
-            for lo in range(0, s, cfg.ce_chunk):
-                hi = min(lo + cfg.ce_chunk, s)
-                nll, cnt = _ce_piece(x[:, lo:hi], targets[:, lo:hi], table,
-                                     cfg, ctx)
-                nll_total = nll_total + nll
-                count = count + cnt
-            denom = jnp.maximum(count, 1)
-            return nll_total / denom, denom
-        nll, cnt = _ce_piece(x, targets, table, cfg, ctx)
-        denom = jnp.maximum(cnt, 1)
+        logits = jnp.einsum("bsd,dv->bsv", x, table).astype(jnp.float32)
+        logits = softcap(logits, cfg.final_softcap)
+        logits = constrain(logits, ctx.mesh, P(ctx.bspec, None, "model"))
+        lse = jax.nn.logsumexp(logits, axis=-1)  # (B,S)
+        mask = (targets >= 0)
+        safe = jnp.maximum(targets, 0)
+        lbl_w = jnp.take(table, safe, axis=1)            # (d, B, S)
+        lbl_logit = jnp.einsum("bsd,dbs->bs", x, lbl_w).astype(jnp.float32)
+        lbl_logit = softcap(lbl_logit, cfg.final_softcap)
+        nll = ((lse - lbl_logit) * mask).sum()
+        denom = jnp.maximum(mask.sum(), 1)
         return nll / denom, denom
 
 
@@ -360,9 +315,9 @@ def lm_loss(params, batch, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes):
     ctx = Ctx(cfg, mesh, axes)
     x, positions = lm_inputs(params, batch, cfg)
     x = ctx.bconstrain(x)
-    n_periods, tail_kinds = split_layers(cfg)
+    _, tail_kinds = split_layers(cfg)
     x, stats, _ = run_stack(params, x, positions, ctx, cfg.block_pattern,
-                            n_periods, tail_kinds)
+                            tail_kinds)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     targets = batch["targets"]
     if cfg.frontend == "vision" and "media_embed" in batch:
@@ -412,9 +367,9 @@ def lm_prefill(params, batch, cfg: ModelConfig, mesh: Mesh, axes: MeshAxes):
     ctx = Ctx(cfg, mesh, axes)
     x, positions = lm_inputs(params, batch, cfg)
     x = ctx.bconstrain(x)
-    n_periods, tail_kinds = split_layers(cfg)
+    _, tail_kinds = split_layers(cfg)
     x, _, caches = run_stack(params, x, positions, ctx, cfg.block_pattern,
-                             n_periods, tail_kinds, collect_cache=True)
+                             tail_kinds, collect_cache=True)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, x[:, -1:], cfg)
     return caches, logits
@@ -426,7 +381,7 @@ def lm_decode(params, caches, token, pos, cfg: ModelConfig, mesh: Mesh,
     _refuse_serving(cfg)
     ctx = Ctx(cfg, mesh, axes)
     x = embed_tokens(params, token, cfg)
-    n_periods, tail_kinds = split_layers(cfg)
+    _, tail_kinds = split_layers(cfg)
 
     def body(x, scanned):
         p_period, cache_period = scanned
@@ -437,11 +392,7 @@ def lm_decode(params, caches, token, pos, cfg: ModelConfig, mesh: Mesh,
             new_caches[f"b{i}"] = nc
         return x, new_caches
 
-    if cfg.unroll_stack:
-        x, new_scan = _unrolled_scan(body, x, (params["scan"], caches["scan"]),
-                                     n_periods)
-    else:
-        x, new_scan = jax.lax.scan(body, x, (params["scan"], caches["scan"]))
+    x, new_scan = jax.lax.scan(body, x, (params["scan"], caches["scan"]))
     new_tail = {}
     for i, kind in enumerate(tail_kinds):
         x, nc = block_decode(params["tail"][f"t{i}"], kind, x,

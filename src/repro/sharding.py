@@ -11,7 +11,6 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.layers import LP, is_lp
@@ -109,7 +108,3 @@ def batch_spec(axes: MeshAxes, ndim: int, batch_dim: int = 0) -> P:
 
 def constrain(x, mesh: Mesh, spec: P):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-
-def batch_size_divisor(mesh: Mesh, axes: MeshAxes) -> int:
-    return int(np.prod([mesh.shape[a] for a in axes.batch]))

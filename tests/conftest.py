@@ -2,8 +2,9 @@ import os
 import sys
 from pathlib import Path
 
-# smoke tests and benches must see 1 device — the 512-device override lives
-# ONLY in launch/dryrun.py (run as a subprocess in test_dryrun).
+# smoke tests and benches see the host's one CPU device; a test that needs a
+# mesh of several runs a subprocess with
+# XLA_FLAGS=--xla_force_host_platform_device_count=N.
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 import jax  # noqa: E402

@@ -103,3 +103,38 @@ def test_shape_cells_cover_assignment():
     assert len(cells) == 34
     long_runners = {a for a, s in cells if s == "long_500k"}
     assert long_runners == {"gemma2-27b", "rwkv6-7b", "recurrentgemma-9b"}
+
+
+@pytest.mark.parametrize("arch", ["tinyllama-1.1b", "gemma2-27b"])
+def test_masked_cross_entropy_is_the_plain_one(arch):
+    """The head's loss is a float32 log-softmax cross-entropy averaged over
+    the targets >= 0: through an untied ``lm_head`` (tinyllama), and
+    through the tied embedding with gemma2's final-logit soft cap."""
+    from repro.models.transformer import Ctx, masked_cross_entropy
+    cfg = configs.get_smoke_config(arch)
+    assert cfg.tie_embeddings == (arch == "gemma2-27b")
+    model = build_model(cfg, MESH)
+    params, _ = split_lp_tree(model.init(jax.random.key(0)))
+    params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    rng = np.random.default_rng(4)
+    x = rng.standard_normal((2, 16, cfg.d_model)).astype(np.float32)
+    targets = rng.integers(0, cfg.vocab_size, (2, 16)).astype(np.int32)
+    targets[0, :5] = -1
+    targets[1, -3:] = -1
+    ctx = Ctx(cfg, MESH, model.axes)
+    loss, count = jax.jit(lambda p, x, t: masked_cross_entropy(
+        p, x, t, cfg, ctx))(params, x, targets)
+
+    table = np.asarray(params["embed"]).T if cfg.tie_embeddings \
+        else np.asarray(params["lm_head"])
+    logits = x.astype(np.float64) @ table.astype(np.float64)
+    if cfg.final_softcap:
+        logits = cfg.final_softcap * np.tanh(logits / cfg.final_softcap)
+    logp = logits - np.log(np.exp(logits - logits.max(-1, keepdims=True))
+                           .sum(-1, keepdims=True)) - logits.max(-1,
+                                                                 keepdims=True)
+    mask = targets >= 0
+    nll = -np.take_along_axis(logp, np.maximum(targets, 0)[..., None],
+                              axis=-1)[..., 0]
+    assert int(count) == mask.sum() == 24
+    np.testing.assert_allclose(float(loss), nll[mask].mean(), rtol=1e-5)

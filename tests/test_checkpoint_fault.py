@@ -175,8 +175,8 @@ def test_run_with_restarts_honors_backoff(monkeypatch):
 
 
 def test_elastic_restore_other_mesh(tmp_path):
-    """Same checkpoint restores under different mesh shardings (1x1 here;
-    the 512-device variant is exercised by the dry-run subprocess test)."""
+    """Same checkpoint restores under the shardings of another mesh's
+    model (1x1 here)."""
     from repro.launch.steps import abstract_params
     from repro.models.layers import split_lp_tree
     from repro.models.model import build_model

@@ -1,11 +1,10 @@
-"""§Perf optimization knobs must be numerics-preserving: chunked CE, chunked
-(flash-style) XLA attention, windowed ring KV cache, remat policies."""
+"""The windowed ring KV cache of local-attention layers must decode as the
+full cache does."""
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro import configs
 from repro.launch.mesh import make_local_mesh
@@ -13,50 +12,6 @@ from repro.models.layers import split_lp_tree
 from repro.models.model import build_model
 
 MESH = make_local_mesh(1, 1)
-
-
-def _loss(cfg, params, batch):
-    model = build_model(cfg, MESH)
-    loss, _ = jax.jit(model.loss_fn)(params, batch)
-    return float(loss)
-
-
-def test_chunked_ce_matches_full():
-    cfg = configs.get_smoke_config("tinyllama-1.1b")
-    model = build_model(cfg, MESH)
-    params, _ = split_lp_tree(model.init(jax.random.key(0)))
-    rng = np.random.default_rng(0)
-    batch = {"tokens": rng.integers(0, cfg.vocab_size, (2, 64)).astype(np.int32),
-             "targets": rng.integers(0, cfg.vocab_size, (2, 64)).astype(np.int32)}
-    full = _loss(cfg, params, batch)
-    chunked = _loss(dataclasses.replace(cfg, ce_chunk=16), params, batch)
-    assert chunked == pytest.approx(full, rel=1e-5)
-
-
-@pytest.mark.parametrize("arch", ["tinyllama-1.1b", "gemma2-27b"])
-def test_chunked_attention_matches_full(arch):
-    cfg = configs.get_smoke_config(arch)
-    model = build_model(cfg, MESH)
-    params, _ = split_lp_tree(model.init(jax.random.key(0)))
-    rng = np.random.default_rng(1)
-    batch = {"tokens": rng.integers(0, cfg.vocab_size, (2, 64)).astype(np.int32),
-             "targets": rng.integers(0, cfg.vocab_size, (2, 64)).astype(np.int32)}
-    full = _loss(cfg, params, batch)
-    chunked = _loss(dataclasses.replace(cfg, attn_kv_chunk=16), params, batch)
-    assert chunked == pytest.approx(full, rel=2e-3)
-
-
-def test_remat_policies_match():
-    cfg = configs.get_smoke_config("tinyllama-1.1b")
-    model = build_model(cfg, MESH)
-    params, _ = split_lp_tree(model.init(jax.random.key(0)))
-    rng = np.random.default_rng(2)
-    batch = {"tokens": rng.integers(0, cfg.vocab_size, (2, 32)).astype(np.int32),
-             "targets": rng.integers(0, cfg.vocab_size, (2, 32)).astype(np.int32)}
-    base = _loss(cfg, params, batch)
-    for pol in ("dots", "none"):
-        v = _loss(dataclasses.replace(cfg, remat_policy=pol), params, batch)
-        assert v == pytest.approx(base, rel=1e-5), pol
 
 
 def test_window_ring_cache_matches_full_cache():

@@ -87,6 +87,58 @@ def test_sharding_rules_divisibility_and_dedupe():
     assert len(spec2) == 2
 
 
+RNN_SHARDING = r"""
+import json, sys
+import jax
+from repro import configs
+from repro.launch.mesh import make_local_mesh
+from repro.launch.steps import abstract_params
+from repro.models.model import build_model
+
+mesh = make_local_mesh(1, 2)
+_, shardings = abstract_params(
+    build_model(configs.get_smoke_config(sys.argv[1]), mesh))
+out = [[[getattr(k, "key", None) for k in path],
+        [s if s is None or isinstance(s, str) else list(s) for s in sh.spec]]
+       for path, sh in jax.tree_util.tree_leaves_with_path(shardings)]
+print(json.dumps(out))
+"""
+
+# each block's parameters with a recurrent-width dimension
+RNN_LEAVES = {
+    "rwkv6-7b": ("time_mix", {"w0", "u", "w_r", "w_k", "w_v", "w_g", "w_o",
+                              "ln_w", "ln_b"}),
+    "recurrentgemma-9b": ("rec", {"w_in_x", "w_in_gate", "conv_w", "conv_b",
+                                  "w_a", "b_a", "w_x", "b_x", "lambda_p",
+                                  "w_out"}),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(RNN_LEAVES))
+def test_recurrent_width_is_sharded_over_model(arch):
+    """On a (data 1, model 2) mesh every parameter of the recurrent blocks
+    that has a recurrent-width dimension splits it over ``model``."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=str(root / "src"))
+    p = subprocess.run([sys.executable, "-c", RNN_SHARDING, arch], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    block, names = RNN_LEAVES[arch]
+    seen = set()
+    for path, spec in json.loads(p.stdout.strip().splitlines()[-1]):
+        if block in path and path[-1] in names:
+            seen.add(path[-1])
+            assert "model" in spec, (path, spec)
+    assert seen == names
+
+
 def test_serve_batch_all_families():
     rng = np.random.default_rng(0)
     for arch in ("smollm-360m", "rwkv6-7b"):
